@@ -4,9 +4,8 @@ interpret mode here — on TPU it is the deployment path).
 Rows are dicts {name, us, derived, montmuls?, backend} so `run.py` can
 emit both the CSV lines and the machine-readable ``BENCH_crypto.json``
 perf-trajectory file.  The library-vs-engine pairs (`montmul`,
-`mont_exp`, `he_matvec`) are the acceptance gauge for the fused kernels:
-`mont_exp_fused` must beat the per-step `ops.mont_exp_bits` ladder
-(2×nbits separate pallas_calls) by ≥2× at batch ≥128.
+`mont_exp`, `he_matvec`) compare the RNS kernels with the library.
+These are CPU timings of the Pallas interpreter, not chip numbers.
 
 Guard rows: every ``*_engine_auto_*`` row carries ``guard_vs`` naming
 its library counterpart plus ``guard_max_ratio`` — `check_guards`
@@ -84,7 +83,7 @@ def run(smoke: bool = False) -> list[dict]:
     rows = []
     mod_bits = (256,) if smoke else (256, 1024)
     batch = 64 if smoke else 256
-    # --- Montgomery product: library vs CIOS kernel vs RNS pipeline ------
+    # --- Montgomery product: library vs RNS pipeline ---------------------
     eng_auto = engine_mod.CryptoEngine(backend="pallas-interpret",
                                        pipeline="auto")
     for bits in mod_bits:
@@ -98,18 +97,13 @@ def run(smoke: bool = False) -> list[dict]:
         us_lib = _time(jit_lib, A, A)
         rows.append(_row(lib_name, us_lib,
                          f"{batch/us_lib:.2f}mul_per_us", montmuls=batch))
-        # full-batch tile: one grid program (interpret overhead is per
-        # program, so the honest interpret tiling is the biggest tile)
-        us = _time(lambda a, b: ops.montmul(a, b, mod, tile_b=batch,
-                                            interpret=True), A, A)
-        rows.append(_row(f"montmul_pallas_interp_{bits}b_x{batch}", us,
-                         f"{batch/us:.2f}mul_per_us",
-                         backend="pallas-interpret", montmuls=batch))
         ctx = rns.for_modulus(mod)
         us = _time(lambda a, b: rns.mont_mul(ctx, a, b), A, A)
         rows.append(_row(f"montmul_rns_jnp_{bits}b_x{batch}", us,
                          f"{batch/us:.2f}mul_per_us;lib_vs_rns="
                          f"{us_lib/us:.2f}x", montmuls=batch))
+        # full-batch tile: one grid program (interpret overhead is per
+        # program, so the honest interpret tiling is the biggest tile)
         us = _time(lambda a, b: ops.rns_montmul(a, b, mod, tile_b=batch,
                                                 interpret=True), A, A)
         rows.append(_row(f"montmul_rns_interp_{bits}b_x{batch}", us,
@@ -127,8 +121,7 @@ def run(smoke: bool = False) -> list[dict]:
         guard["guard_vs"] = lib_name
         rows.append(guard)
 
-    # --- mont_exp: per-step kernel ladder vs fused single pallas_call ----
-    # (the tentpole acceptance row: fused ≥2× at batch ≥128)
+    # --- mont_exp at 256 bits: library vs engine-routed ----------------
     exp_mod = Modulus.make((1 << 256) - 159)
     exp_batch = 128
     exp_bits_n = 8 if smoke else 16
@@ -144,18 +137,6 @@ def run(smoke: bool = False) -> list[dict]:
                    Bm, ebits)
     rows.append(_row(f"mont_exp_lib_256b_x{exp_batch}_e{exp_bits_n}", us_lib,
                      "", montmuls=exp_mm))
-    us_step = _time(lambda b, e: ops.mont_exp_bits(b, e, exp_mod,
-                                                   interpret=True), Bm, ebits)
-    rows.append(_row(f"mont_exp_perstep_256b_x{exp_batch}_e{exp_bits_n}",
-                     us_step, f"pallas_calls={2*exp_bits_n}",
-                     backend="pallas-interpret", montmuls=exp_mm))
-    us_fused = _time(lambda b, e: ops.mont_exp_fused(b, e, exp_mod,
-                                                     interpret=True),
-                     Bm, ebits)
-    rows.append(_row(f"mont_exp_fused_256b_x{exp_batch}_e{exp_bits_n}",
-                     us_fused,
-                     f"pallas_calls=1;speedup_vs_perstep={us_step/us_fused:.2f}x",
-                     backend="pallas-interpret", montmuls=exp_mm))
     guard = _row(f"mont_exp_engine_auto_256b_x{exp_batch}_e{exp_bits_n}",
                  _time(jax.jit(lambda b, e: eng_auto.mont_exp_bits(
                      b, e, exp_mod)), Bm, ebits),
@@ -256,14 +237,6 @@ def run(smoke: bool = False) -> list[dict]:
     guard["guard_vs"] = (f"he_matvec_lib_window{window}_{enc_batch}x{mv_m}"
                          f"_w{width}_{kb}b")
     rows.append(guard)
-    eng = engine_mod.CryptoEngine(backend="pallas-interpret",
-                                  pipeline="cios")
-    us_e = _time(lambda cc, dd: protocols.he_matvec(
-        pub, cc, exps, width, window=window, digits=dd, engine=eng), c, dig)
-    rows.append(_row(f"he_matvec_fused_window{window}_{enc_batch}x{mv_m}"
-                     f"_w{width}_{kb}b", us_e,
-                     f"pallas_calls=1;lib_vs_fused={us_w/us_e:.2f}x",
-                     backend="pallas-interpret", montmuls=mv_mm))
     eng_r = engine_mod.CryptoEngine(backend="pallas-interpret",
                                     pipeline="rns")
     us_r = _time(lambda cc, dd: protocols.he_matvec(
@@ -322,7 +295,8 @@ def run(smoke: bool = False) -> list[dict]:
     a = ring.from_numpy_u64(RNG.integers(0, 1 << 64, (M, K), dtype=np.uint64))
     b = ring.from_numpy_u64(RNG.integers(0, 1 << 64, (K, N), dtype=np.uint64))
     us = _time(lambda x, y: ops.ring_matmul(x, y, tm=min(M, 64),
-                                            tn=min(N, 64)), a, b)
+                                            tn=min(N, 64), interpret=True),
+               a, b)
     rows.append(_row(f"ring64_matmul_pallas_{M}x{K}x{N}", us,
                      f"{2*M*K*N/us/1e6:.2f}Gmac_per_s",
                      backend="pallas-interpret"))

@@ -285,7 +285,10 @@ def main() -> None:
     if args.guards:
         check_committed_guards()
         return
+    from repro.launch import compile_cache
+    compile_cache.enable()
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in BENCHES.items():
         if args.only and name != args.only:
             continue
@@ -294,10 +297,13 @@ def main() -> None:
                 fn(args.paper, smoke=args.smoke)
             else:
                 fn(args.paper)
-        except Exception as e:   # noqa: BLE001 — report and continue
+        except Exception as e:   # noqa: BLE001 — report, run the rest
             _csv(f"{name}.ERROR", 0.0, f"{type(e).__name__}:{e}")
             import traceback
             traceback.print_exc(file=sys.stderr)
+            failed.append(name)
+    if failed:
+        raise SystemExit(f"benchmarks failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -10,6 +10,11 @@ least that of singleton batches.  Full mode adds one socket row (real
 party processes over TCP) and records the wire invariant measured ==
 analytic bytes for the `infer.wx_share` tag.
 
+A chip has one owner: the in-process rows run on this process's JAX
+device, and the socket row's party processes run on the host CPU
+(`cluster.PARTY_PLATFORM`, recorded in the row), so none of them reaches
+for a chip this process holds.
+
   PYTHONPATH=src python -m benchmarks.run --only serve [--smoke]
 """
 from __future__ import annotations
@@ -107,7 +112,7 @@ def _socket_row(n_req: int, batch: int) -> dict:
     """One distributed row: real party processes over TCP, plus the wire
     invariant (measured frame bytes == analytic meter) for the serving
     tag — the same per-tag identity training asserts."""
-    from repro.launch.cluster import SocketCluster
+    from repro.launch.cluster import PARTY_PLATFORM, SocketCluster
     k, backend = 3, "mock"
     parties, y, cfg, names, parts = _setup(k, backend)
     with SocketCluster(parties, y, cfg) as cl:
@@ -119,6 +124,7 @@ def _socket_row(n_req: int, batch: int) -> dict:
     measured = meters["measured"].by_tag["infer.wx_share"]
     return _row(f"serve.socket.k{k}.{backend}.b{batch}", stats, k,
                 backend, batch, "socket", n_req,
+                party_platform=PARTY_PLATFORM,
                 wx_bytes_analytic=int(analytic),
                 wx_bytes_measured=int(measured),
                 wire_ok=bool(analytic == measured

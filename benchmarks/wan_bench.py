@@ -13,6 +13,10 @@ and reports the measured wall-clock next to that analytic model — the
 deployment-economics view of docs/transports.md §WAN, and the guard
 that round-count regressions show up as *seconds* at WAN latencies.
 
+The conductor is this process; the party processes run on the host CPU
+(`cluster.PARTY_PLATFORM`, recorded in the report), so the chip, if
+any, has this process as its one owner.
+
   PYTHONPATH=src python -m benchmarks.wan_bench [--smoke]
 
 writes BENCH_wan.json at the repo root (committed, like BENCH_crypto);
@@ -52,6 +56,7 @@ def _mock_run(chaos, iters: int, nb: int, k: int = 3):
 
 
 def run(smoke: bool = False) -> dict:
+    from repro.launch.cluster import PARTY_PLATFORM
     from repro.runtime.chaos import PROFILES
 
     iters = 2 if smoke else 4
@@ -95,7 +100,8 @@ def run(smoke: bool = False) -> dict:
                         f"wan_extra_s={extra:.3f}"),
         })
     return {"schema": "bench_wan/v1", "parties": 3, "iters": iters,
-            "batch": nb, "he_backend": "mock", "rows": rows}
+            "batch": nb, "he_backend": "mock",
+            "party_platform": PARTY_PLATFORM, "rows": rows}
 
 
 def write_report(report: dict) -> pathlib.Path:
@@ -107,6 +113,8 @@ def write_report(report: dict) -> pathlib.Path:
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny sizes, wan20 only, no file written")

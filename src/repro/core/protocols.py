@@ -156,14 +156,9 @@ def he_matvec(pub: paillier.PublicKey, cts: jnp.ndarray,
     jnp library — bit-identical either way."""
     eng = engine if engine is not None else engine_mod.get_engine()
     # Route through the engine when it has somewhere better to go than
-    # the jitted library ladders here: a mesh, the RNS pipeline (either
-    # form), or the CIOS kernel where engine._route actually selects it
-    # (compiled backend, or an explicitly pinned pipeline).  Interpret-
-    # mode small-modulus ops stay on the library path — never slower
-    # than the library (kernel_bench guard rows).
-    route = None if eng.sharded else eng._route(pub.mod_n2)
-    engine_routed = (eng.sharded or route in ("rns", "rns-jnp")
-                     or (eng.uses_kernels and route == "cios"))
+    # the jitted library ladders here: a mesh or the RNS pipeline (either
+    # form).  Library-routed moduli stay on the jitted ladders below.
+    engine_routed = eng.sharded or eng._route(pub.mod_n2) != "lib"
     if window <= 1:
         if engine_routed:
             bits = fixed_point.int_bits_msb(exps.astype(_U32), width)

@@ -77,7 +77,13 @@ def limbs_to_int(limbs) -> int:
 # ---------------------------------------------------------------------------
 # Carry / borrow sweeps (exact, one sequential pass along the limb axis)
 # ---------------------------------------------------------------------------
+#
+# The sweeps and the schoolbook product are jitted: called eagerly (as
+# Paillier encryption and CRT decryption call them), an unjitted
+# `lax.scan` or `fori_loop` is traced and compiled again on every call —
+# on a TPU a compile per call.  Jitted, they compile once per shape.
 
+@jax.jit
 def carry_sweep(t: jnp.ndarray) -> jnp.ndarray:
     """Exact normalization: limbs < 2^12 afterwards.  Input limbs may hold
     any uint32 value; the final carry out of the top limb is dropped
@@ -93,6 +99,7 @@ def carry_sweep(t: jnp.ndarray) -> jnp.ndarray:
     return jnp.moveaxis(ys, 0, -1)
 
 
+@jax.jit
 def _sub_with_borrow(a: jnp.ndarray, b: jnp.ndarray):
     """a - b limbwise for canonical inputs.  Returns (diff, borrow_out)
     where diff is canonical and borrow_out is 1 where a < b."""
@@ -108,6 +115,7 @@ def _sub_with_borrow(a: jnp.ndarray, b: jnp.ndarray):
     return jnp.moveaxis(ys, 0, -1), borrow
 
 
+@jax.jit
 def _add_limbs(a: jnp.ndarray, b: jnp.ndarray):
     """a + b, canonical inputs -> (canonical sum mod 2^(12L), carry_out)."""
     s = a + b
@@ -335,6 +343,7 @@ def mont_exp_const(base_mont: jnp.ndarray, e: int, mod: Modulus) -> jnp.ndarray:
 # Plain (non-modular) products used by Paillier
 # ---------------------------------------------------------------------------
 
+@functools.partial(jax.jit, static_argnames="out_limbs")
 def big_mul_full(a: jnp.ndarray, b: jnp.ndarray, out_limbs: int) -> jnp.ndarray:
     """Exact product of canonical inputs, truncated/padded to out_limbs.
     Accumulation bound: min(La, Lb) * 2^24 < 2^32 for <=255 limbs."""

@@ -22,15 +22,15 @@ so the switch is purely a performance knob: select with the
 Orthogonal to the backend, the ``pipeline`` field picks the *arithmetic*
 each op runs (docs/engine.md §RNS):
 
-* ``cios`` — radix-2^12 CIOS limb loops (mask/shift only; wins at small
-             moduli and is the only pipeline the sharded path runs);
+* ``cios`` — the radix-2^12 CIOS limb loops of `crypto.bigint`, as plain
+             jitted jnp on every backend (mask/shift only; wins at small
+             moduli);
 * ``rns``  — the residue-number-system channel pipeline (`crypto.rns`):
              montmul becomes one pointwise round + two exact f32
-             matmuls, the MXU-shaped form that wins at large moduli;
+             matmuls, the MXU-shaped form; the kernel backends run it as
+             the fused Pallas kernels, ``jnp`` as library code;
 * ``auto`` — per-modulus routing at `RNS_MIN_BITS`: RNS at ≥ 512-bit
-             moduli, CIOS/library below — so engine-routed ops are
-             never slower than the library at any committed key size
-             (benchmarks/kernel_bench.py guards this).
+             moduli, the CIOS library below.
 
 Fixed-base exponentiation (`fixed_base_exp`, fed by
 `crypto.fixed_base.FixedBaseTable`) is always RNS — the table stores
@@ -65,10 +65,10 @@ ENV_VAR = "REPRO_CRYPTO_ENGINE"
 PIPELINES = ("auto", "cios", "rns")
 PIPELINE_ENV_VAR = "REPRO_CRYPTO_PIPELINE"
 # Modulus width (bits of N, i.e. of n² for ciphertext ops) at and above
-# which ``auto`` routes to the RNS pipeline.  Measured crossover on CPU
+# which ``auto`` routes to the RNS pipeline.  Set from CPU timings
 # (BENCH_crypto.json): at 1024-bit montmul RNS runs 0.7–0.8× the
 # library; at 256-bit its ~14 integer-divides per round lose to CIOS's
-# pure mask/shift arithmetic.  docs/engine.md §amortization.
+# pure mask/shift arithmetic.  Not measured on the chip yet.
 RNS_MIN_BITS = 512
 
 
@@ -122,10 +122,8 @@ class CryptoEngine:
         compiled for TPU).
       pipeline: ``"cios"`` | ``"rns"`` | ``"auto"`` — which arithmetic
         the big ops run.  ``auto`` (default) picks per modulus at
-        `RNS_MIN_BITS` and additionally drops *interpret-mode* small-
-        modulus ops to the jnp library (an interpreted CIOS kernel can
-        never beat the same loop jitted directly).  Explicit values pin
-        the arithmetic — the parity suite uses that.
+        `RNS_MIN_BITS`.  Explicit values pin the arithmetic — the
+        parity suite uses that.
       tile_b: batch tile for the montmul / fused-ladder kernels.
       tile_m: output-column tile for the fused HE matvec kernel.
       chunk_n: ciphertext-row chunk bounding the matvec power table's
@@ -189,23 +187,17 @@ class CryptoEngine:
 
     def _route(self, mod: Modulus) -> str:
         """Pick the arithmetic for one op on modulus `mod`:
-        ``"lib"`` (bigint CIOS loops), ``"cios"`` (CIOS kernel),
-        ``"rns-jnp"`` (`crypto.rns` library) or ``"rns"`` (RNS kernel).
-
-        ``auto`` routes by modulus width (`RNS_MIN_BITS`), and below the
-        threshold keeps the CIOS *kernel* only for the compiled backend:
-        in interpret mode the kernel is the library algorithm plus
-        interpreter overhead, so the library path is strictly faster —
-        this is what makes engine-routed interpret mode never slower
-        than the library (the kernel_bench guard rows assert it)."""
+        ``"lib"`` (bigint CIOS loops), ``"rns-jnp"`` (`crypto.rns`
+        library) or ``"rns"`` (RNS Pallas kernels).  Every kernel the
+        ``pallas`` backend can reach is an RNS kernel: those are the ones
+        the TPU compiler accepts (tests/test_tpu_compile.py)."""
         pipe = resolve_pipeline(self.pipeline)
         if pipe == "auto":
-            if mod.value.bit_length() >= RNS_MIN_BITS:
-                return "rns" if self.uses_kernels else "rns-jnp"
-            return "cios" if self.backend == "pallas" else "lib"
-        if pipe == "rns":
-            return "rns" if self.uses_kernels else "rns-jnp"
-        return "cios" if self.uses_kernels else "lib"
+            pipe = ("rns" if mod.value.bit_length() >= RNS_MIN_BITS
+                    else "cios")
+        if pipe == "cios":
+            return "lib"
+        return "rns" if self.uses_kernels else "rns-jnp"
 
     # -- fused hot-path ops -------------------------------------------------
     def mont_mul(self, a: jnp.ndarray, b: jnp.ndarray,
@@ -229,11 +221,8 @@ class CryptoEngine:
             from repro.crypto import rns
             return rns.mont_mul(rns.for_modulus(mod), a, b)
         from repro.kernels import ops
-        if route == "rns":
-            return ops.rns_montmul(a, b, mod, tile_b=self.tile_b,
-                                   interpret=self.interpret)
-        return ops.montmul(a, b, mod, tile_b=self.tile_b,
-                           interpret=self.interpret)
+        return ops.rns_montmul(a, b, mod, tile_b=self.tile_b,
+                               interpret=self.interpret)
 
     def mont_exp_bits(self, base: jnp.ndarray, bits: jnp.ndarray,
                       mod: Modulus) -> jnp.ndarray:
@@ -259,12 +248,8 @@ class CryptoEngine:
             from repro.crypto import rns
             return rns.mont_exp_bits(rns.for_modulus(mod), base, bits)
         from repro.kernels import ops
-        if route == "rns":
-            return ops.rns_mont_exp_fused(base, bits, mod,
-                                          tile_b=self.tile_b,
-                                          interpret=self.interpret)
-        return ops.mont_exp_fused(base, bits, mod, tile_b=self.tile_b,
-                                  interpret=self.interpret)
+        return ops.rns_mont_exp_fused(base, bits, mod, tile_b=self.tile_b,
+                                      interpret=self.interpret)
 
     def mont_exp_const(self, base: jnp.ndarray, e: int,
                        mod: Modulus) -> jnp.ndarray:
@@ -291,32 +276,26 @@ class CryptoEngine:
 
         Kernel backends run the fused kernel; a mesh-sharded engine
         shards the ciphertext-row axis and ⊕-combines partials across
-        devices.  For the plain jnp single-device engine, callers
-        (`protocols.he_matvec`) route to the jitted library ladders
-        instead — this method is reachable on the jnp backend only via
-        the sharded path, whose shard bodies carry their own library
-        ladder.
+        devices.  The ``lib`` route is the library ladder — the shard
+        body of a sharded jnp engine; single-device callers
+        (`protocols.he_matvec`) run their own jitted copy of it.
         """
         if self.sharded:
             from repro.distributed import he_sharding
             return he_sharding.sharded_he_matvec(self, cts, digits, mod,
                                                  window)
+        digits = jnp.asarray(digits, _U32)
         route = self._route(mod)
+        if route == "lib":
+            return _lib_he_matvec(cts, digits, mod, window)
         if route == "rns-jnp":
             from repro.crypto import rns
-            return rns.he_matvec(rns.for_modulus(mod), cts,
-                                 jnp.asarray(digits, _U32), window)
+            return rns.he_matvec(rns.for_modulus(mod), cts, digits, window)
         from repro.kernels import ops
-        if route == "rns":
-            return ops.rns_he_matvec_fused(cts, jnp.asarray(digits, _U32),
-                                           mod, window=window,
-                                           tile_m=self.tile_m,
-                                           chunk_n=self.chunk_n,
-                                           interpret=self.interpret)
-        return ops.he_matvec_fused(cts, jnp.asarray(digits, _U32), mod,
-                                   window=window, tile_m=self.tile_m,
-                                   chunk_n=self.chunk_n,
-                                   interpret=self.interpret)
+        return ops.rns_he_matvec_fused(cts, digits, mod, window=window,
+                                       tile_m=self.tile_m,
+                                       chunk_n=self.chunk_n,
+                                       interpret=self.interpret)
 
     def fixed_base_exp(self, table, digits, mod: Modulus) -> jnp.ndarray:
         """Windowed fixed-base exponentiation from a persistent table.
@@ -358,6 +337,32 @@ class CryptoEngine:
         """Drop Montgomery-domain residues back to plain canonical form."""
         one = jnp.zeros(mod.L, _U32).at[0].set(1)
         return self.mont_mul(a, one, mod)
+
+
+def _lib_he_matvec(cts, digits, mod: Modulus, window: int) -> jnp.ndarray:
+    """Windowed matvec on the bigint library: power table, then per
+    digit level `window` squarings and a log-depth tree-⊕ of the selected
+    powers (the schedule of `protocols._he_matvec_windowed`)."""
+    n, m, levels = digits.shape
+    one = bigint.mont_one(mod)
+    table = [jnp.broadcast_to(one, cts.shape), cts]
+    for _ in range(2, 1 << window):
+        table.append(bigint.mont_mul(table[-1], cts, mod))
+    table = jnp.stack(table, axis=0)                  # (2^w, n, L)
+    acc = jnp.broadcast_to(one, (m, mod.L))
+    for lvl in range(levels):
+        for _ in range(window):
+            acc = bigint.mont_mul(acc, acc, mod)
+        c = jnp.take_along_axis(
+            table[:, :, None, :], digits[None, :, :, lvl, None], axis=0)[0]
+        while c.shape[0] > 1:
+            half = c.shape[0] // 2
+            merged = bigint.mont_mul(c[:half], c[half:2 * half], mod)
+            if c.shape[0] % 2:
+                merged = jnp.concatenate([merged, c[2 * half:]], axis=0)
+            c = merged
+        acc = bigint.mont_mul(acc, c[0], mod)
+    return acc
 
 
 def make(name: str | None = None, **kw) -> CryptoEngine:

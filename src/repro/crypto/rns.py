@@ -66,42 +66,68 @@ _ACCUM_CHUNK = 64        # kA-chunk for the no-mod limb accumulation
 # Exact f32 split matmuls
 # ---------------------------------------------------------------------------
 
+def _to_f32(x):
+    # via int32: the TPU kernel compiler has no direct u32 -> f32 cast;
+    # every operand here is < 2^24, so both hops are exact
+    return x.astype(jnp.int32).astype(_F32)
+
+
+def _to_u32(x):
+    return x.astype(jnp.int32).astype(_U32)
+
+
 def _split_halves(x):
-    return ((x & _SPLIT_MASK).astype(_F32), (x >> _SPLIT).astype(_F32))
+    return _to_f32(x & _SPLIT_MASK), _to_f32(x >> _SPLIT)
 
 
 def _dot(a, b):
-    return jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST)
+    return _to_u32(jnp.matmul(a, b, precision=jax.lax.Precision.HIGHEST))
 
 
 def _mod_u28(x, m):
-    """``x mod m`` for uint32 x < 2^28 against the 13-bit channel moduli.
-    Plain hardware remainder: on CPU the pipelined integer divide beats
-    any f32-reciprocal emulation (which needs ~15 memory-bound element
-    ops per call — measured 3× slower end-to-end).  Kept as a named seam
-    so a TPU-compiled build (no native integer divide) can swap in a
-    reciprocal sequence in ONE place; every call site bounds its operand
-    below 2^28 (see the comments there), which is what such a swap needs.
-    """
+    """``x mod m`` for uint32 x < 2^28 against the 13-bit channel moduli,
+    for the library and XLA paths (kernel bodies always use
+    `mod_u28_recip`).  On the CPU a plain `%`: its pipelined integer
+    divide beats the f32-reciprocal form (3× end-to-end there).  On the
+    TPU the reciprocal form: XLA expands a vector `%` into a long
+    sequence that is slow to compile — `to_rns` on 2048 rows at 1024-bit
+    keys compiled in 22.6 s with `%`, most of a cold run's compile time
+    (described v5e, compiled on a CPU host)."""
+    if jax.default_backend() == "tpu":
+        return mod_u28_recip(x, m)
     return x % m
 
 
-def split_matmul_mod(x: jnp.ndarray, t, mods) -> jnp.ndarray:
+def mod_u28_recip(x, m):
+    """``x mod m`` for uint32 x < 2^28 and 2^11 < m < 2^13, through an
+    f32 reciprocal.  The TPU kernel compiler emulates a vector integer
+    remainder with a long instruction sequence: at 1024-bit keys the
+    ladder kernel compiled to 4.2× the code with `%` (11.6 MB vs 2.8 MB
+    for a v5e).  The quotient estimate floor(f32(x)·f32(1/m)) is within
+    one of the true quotient — f32(x) is off by ≤ 8 ≤ m/256, and the two
+    roundings add < 2^17·2^-22 — so one select each way makes it exact."""
+    xi = x.astype(jnp.int32)
+    mi = jnp.asarray(m).astype(jnp.int32)
+    q = jnp.floor(xi.astype(_F32) * (1.0 / mi.astype(_F32)))
+    r = xi - q.astype(jnp.int32) * mi
+    r = jnp.where(r < 0, r + mi, r)
+    return jnp.where(r >= mi, r - mi, r).astype(_U32)
+
+
+def split_matmul_mod(x: jnp.ndarray, t, mods, rem=_mod_u28) -> jnp.ndarray:
     """Exact ``(x @ t) mod mods`` for uint32 entries < 2^13 via four f32
     matmuls of 7-bit halves.  x: (..., k); t: (k, j); mods: (j,)-broadcast.
     Every partial sum is an integer < 2^24 (k ≤ 1040), hence exact in f32
     in any accumulation order; the u32 recombine keeps each congruent
-    term below 2^28 before the final reduction."""
+    term below 2^28 before the final reduction `rem`."""
     xl, xh = _split_halves(x)
     tl, th = _split_halves(t)
-    ll = _dot(xl, tl).astype(_U32)
-    lh = _dot(xl, th).astype(_U32)
-    hl = _dot(xh, tl).astype(_U32)
-    hh = _dot(xh, th).astype(_U32)
-    mid = _mod_u28(lh + hl, mods) << _SPLIT           # lh+hl < 2^25
-    top = _mod_u28(hh, mods) << (2 * _SPLIT)          # hh < 2^24
+    ll, lh = _dot(xl, tl), _dot(xl, th)
+    hl, hh = _dot(xh, tl), _dot(xh, th)
+    mid = rem(lh + hl, mods) << _SPLIT                # lh+hl < 2^25
+    top = rem(hh, mods) << (2 * _SPLIT)               # hh < 2^24
     # ll < 2^24, mid < 2^20, top < 2^27 → sum < 2^28
-    return _mod_u28(ll + mid + top, mods)
+    return rem(ll + mid + top, mods)
 
 
 def _split_matmul_raw(x: jnp.ndarray, t) -> jnp.ndarray:
@@ -109,10 +135,8 @@ def _split_matmul_raw(x: jnp.ndarray, t) -> jnp.ndarray:
     must bound k ≤ _ACCUM_CHUNK so the recombined sum stays < 2^31."""
     xl, xh = _split_halves(x)
     tl, th = _split_halves(t)
-    ll = _dot(xl, tl).astype(_U32)
-    lh = _dot(xl, th).astype(_U32)
-    hl = _dot(xh, tl).astype(_U32)
-    hh = _dot(xh, th).astype(_U32)
+    ll, lh = _dot(xl, tl), _dot(xl, th)
+    hl, hh = _dot(xh, tl), _dot(xh, th)
     return ll + ((lh + hl) << _SPLIT) + (hh << (2 * _SPLIT))
 
 
@@ -141,7 +165,11 @@ class RNSContext:
     the same object per (N, L), so traces cache correctly.
 
     Channel layout of every state vector: ``[base A | base B | m_r]``
-    (kA + kB + 1 = CH channels).  Numpy members become jit constants.
+    (kA + kB + 1 = CH channels).  Every per-channel constant sits in its
+    channel's lane and the extension matrices are (CH, CH) embeddings, so
+    one Montgomery round never slices the lane axis at a base boundary
+    (the TPU kernel compiler refuses lane slices that are not aligned to
+    its 128-lane tiling).  Numpy members become jit constants.
     """
 
     value: int                  # N (host int)
@@ -157,15 +185,18 @@ class RNSContext:
     a_mods: np.ndarray          # (kA,)
     b_mods: np.ndarray          # (kB,)
     all_mods: np.ndarray        # (CH,)
-    t_b: np.ndarray             # (kB, kA+1): (B/bᵢ) mod [a_mods | m_r]
-    t_a: np.ndarray             # (kA, kB+1): (A/aⱼ) mod [b_mods | m_r]
-    vecs: np.ndarray            # (6, CH) packed per-channel constants:
-                                #   0: −N⁻¹ mod bᵢ      (kB)
-                                #   1: (B/bᵢ)⁻¹ mod bᵢ  (kB)
-                                #   2: (A/aⱼ)⁻¹ mod aⱼ  (kA)
-                                #   3: N mod [a|r]      (kA+1)
-                                #   4: B⁻¹ mod [a|r]    (kA+1)
-                                #   5: A mod bᵢ         (kB)
+    t_b: np.ndarray             # (CH, CH): row bᵢ, column aⱼ or m_r:
+                                #   (B/bᵢ) mod that channel; 0 elsewhere
+    t_a: np.ndarray             # (CH, CH): row aⱼ, column bᵢ or m_r:
+                                #   (A/aⱼ) mod that channel; 0 elsewhere
+    vecs: np.ndarray            # (6, CH) per-channel constants in their
+                                # own lanes, 0 in the other lanes:
+                                #   0: −N⁻¹ mod bᵢ      (B lanes)
+                                #   1: (B/bᵢ)⁻¹ mod bᵢ  (B lanes)
+                                #   2: (A/aⱼ)⁻¹ mod aⱼ  (A lanes)
+                                #   3: N mod [a|r]      (A and r lanes)
+                                #   4: B⁻¹ mod [a|r]    (A and r lanes)
+                                #   5: A mod bᵢ         (B lanes)
     pow_mat: np.ndarray         # (L, CH): 2^(12 l) mod channel (to_rns)
     pow_scaled: np.ndarray      # (L, CH): (2^(12 l)·B·R⁻¹ mod N) mod ch —
                                 # to_rns with the Montgomery-radix change
@@ -227,21 +258,23 @@ def make_context(value: int, L: int) -> RNSContext:
     b_mods = np.array(b_list, np.uint32)
     all_mods = np.concatenate([a_mods, b_mods, np.array([m_r], np.uint32)])
     ar = a_list + [m_r]
-    br = b_list + [m_r]
     CH = kA + kB + 1
+    a_lanes = list(range(kA)) + [CH - 1]
+    b_lanes = slice(kA, kA + kB)
 
-    t_b = np.array([[(B // bi) % aj for aj in ar] for bi in b_list],
-                   np.uint32)
-    t_a = np.array([[(A // aj) % bi for bi in br] for aj in a_list],
-                   np.uint32)
+    t_b = np.zeros((CH, CH), np.uint32)
+    t_b[b_lanes, a_lanes] = [[(B // bi) % m for m in ar] for bi in b_list]
+    t_a = np.zeros((CH, CH), np.uint32)
+    t_a[:kA, kA:] = [[(A // aj) % m for m in b_list + [m_r]]
+                     for aj in a_list]
 
     vecs = np.zeros((6, CH), np.uint32)
-    vecs[0, :kB] = [(-pow(N, -1, bi)) % bi for bi in b_list]
-    vecs[1, :kB] = [pow(B // bi, -1, bi) for bi in b_list]
+    vecs[0, b_lanes] = [(-pow(N, -1, bi)) % bi for bi in b_list]
+    vecs[1, b_lanes] = [pow(B // bi, -1, bi) for bi in b_list]
     vecs[2, :kA] = [pow(A // aj, -1, aj) for aj in a_list]
-    vecs[3, :kA + 1] = [N % m for m in ar]
-    vecs[4, :kA + 1] = [pow(B, -1, m) for m in ar]
-    vecs[5, :kB] = [A % bi for bi in b_list]
+    vecs[3, a_lanes] = [N % m for m in ar]
+    vecs[4, a_lanes] = [pow(B, -1, m) for m in ar]
+    vecs[5, b_lanes] = [A % bi for bi in b_list]
 
     pow_mat = np.stack([_residues(1 << (LIMB_BITS * l), all_mods)
                         for l in range(L)])
@@ -285,47 +318,51 @@ def for_modulus(mod: Modulus) -> RNSContext:
 # ---------------------------------------------------------------------------
 
 def montmul_channels(x, y, mods, t_b, t_a, vecs, *, kA: int, kB: int,
-                     ainv_r: int):
+                     ainv_r: int, rem=_mod_u28):
     """One RNS Montgomery round on channel states: returns the residues
     of ``t = x·y·B⁻¹`` with t < (kB+2)·N, given x, y < (kB+2)·N.
 
     Pure jnp on plain arrays, so kernel bodies trace it inline exactly as
     the library path runs it (`kernels/montmul.py` reuses this function —
-    the kernels and the library are the same arithmetic by construction).
+    the kernels and the library are the same arithmetic by construction;
+    kernels pass ``rem=mod_u28_recip``, an exact remainder too).
+    Every step runs on all CH lanes; the zeros of the lane-embedded
+    constants keep each base's values in its own lanes, and the redundant
+    channel is read out by a masked lane sum, never by a lane slice.
     """
     CH = kA + kB + 1
-    am = mods[..., :kA]
-    bm = mods[..., kA:kA + kB]
-    rm = mods[..., CH - 1:]
-    armods = jnp.concatenate([am, rm], axis=-1)
-    brmods = jnp.concatenate([bm, rm], axis=-1)
+    nd = max(jnp.ndim(x), jnp.ndim(y))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1,) * (nd - 1) + (CH,),
+                                    nd - 1)
+    in_b = (lane >= kA) & (lane < kA + kB)
 
-    s = _mod_u28(x * y, mods)                            # x·y < 2^26
-    s_ar = jnp.concatenate([s[..., :kA], s[..., CH - 1:]], axis=-1)
-    sb = s[..., kA:kA + kB]
+    def lane_r(v):                                       # (..., 1)
+        # int32 sum: the kernel compiler has no unsigned reductions
+        hit = jnp.where(lane == CH - 1, v.astype(jnp.int32), 0)
+        return _to_u32(jnp.sum(hit, axis=-1, keepdims=True))
 
+    s = rem(x * y, mods)                                 # x·y < 2^26
     # q = −x·y·N⁻¹ mod B, channel-pointwise; ξ its mixed-radix form
-    qb = _mod_u28(sb * vecs[0, :kB], bm)                 # < 2^26
-    xi = _mod_u28(qb * vecs[1, :kB], bm)                 # < 2^26
-    # first base extension (approximate: may add α·B, α < kB — absorbed
-    # by the t < (kB+2)·N bound, never by correctness)
-    qhat = split_matmul_mod(xi, t_b, armods)             # (..., kA+1)
+    # (0 off the B lanes: vecs[0] and vecs[1] vanish there)
+    qb = rem(s * vecs[0], mods)                          # < 2^26
+    xi = rem(qb * vecs[1], mods)                         # < 2^26
+    # first base extension onto the A and r lanes (approximate: may add
+    # α·B, α < kB — absorbed by the t < (kB+2)·N bound, never by
+    # correctness)
+    qhat = split_matmul_mod(xi, t_b, mods, rem)
 
-    # t = (s + q̂·N)/B on base A and the redundant channel
-    t_ar = _mod_u28(                                     # inner < 2^27
-        _mod_u28(s_ar + qhat * vecs[3, :kA + 1], armods)
-        * vecs[4, :kA + 1], armods)                      # outer < 2^26
-    ta = t_ar[..., :kA]
-    tr = t_ar[..., kA:]
+    # t = (s + q̂·N)/B on base A and the redundant channel (0 on B lanes)
+    t_ar = rem(                                          # inner < 2^27
+        rem(s + qhat * vecs[3], mods) * vecs[4], mods)   # < 2^26
 
     # exact second extension A → B (Shenoy–Kumaresan via m_r)
-    xi2 = _mod_u28(ta * vecs[2, :kA], am)                # < 2^26
-    ext = split_matmul_mod(xi2, t_a, brmods)             # (..., kB+1)
-    sig_b = ext[..., :kB]
-    sig_r = ext[..., kB:]
-    alpha = _mod_u28((sig_r + rm - tr) * _U32(ainv_r), rm)   # < 2^27
-    tb = _mod_u28(sig_b + bm - _mod_u28(alpha * vecs[5, :kB], bm), bm)
-    return jnp.concatenate([ta, tb, tr], axis=-1)
+    xi2 = rem(t_ar * vecs[2], mods)                      # A lanes, < 2^26
+    ext = split_matmul_mod(xi2, t_a, mods, rem)          # B and r lanes
+    rm = lane_r(mods)
+    alpha = rem((lane_r(ext) + rm - lane_r(t_ar)) * _U32(ainv_r),
+                rm)                                      # < 2^27
+    tb = rem(ext + mods - rem(alpha * vecs[5], mods), mods)
+    return jnp.where(in_b, tb, t_ar)
 
 
 # ---------------------------------------------------------------------------
@@ -377,7 +414,7 @@ def from_rns(ctx: RNSContext, t: jnp.ndarray) -> jnp.ndarray:
 
     t_a = _jc(ctx, "t_a")
     mr = _U32(ctx.m_r)
-    sig_r = split_matmul_mod(xi, t_a[:, ctx.kB:], mr)    # Σξ'(A/aⱼ) mod m_r
+    sig_r = split_matmul_mod(xi, t_a[:kA, ctx.CH - 1:], mr)   # mod m_r
     alpha = _mod_u28((sig_r + mr - tr) * _U32(ctx.ainv_r), mr)   # < 2^27
 
     # P = Σⱼ ξ'ⱼ · limbs(A/aⱼ): exact, chunked so lazy limbs stay < 2^31

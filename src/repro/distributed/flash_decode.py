@@ -55,7 +55,7 @@ def make_flash_decode(mesh, seq_axis: str | tuple, B: int, S: int,
                       G: int, Hg: int, hd: int, softcap=None):
     """Builds a shard_map'd decode-attention: cache stays sharded on its
     sequence dim over `seq_axis`; only (B,G,Hg,hd)-sized partials move."""
-    from repro.distributed.shardmap_compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = seq_axis if isinstance(seq_axis, tuple) else (seq_axis,)
@@ -105,11 +105,11 @@ def _main() -> None:   # pragma: no cover (driver)
              jax.ShapeDtypeStruct((B, S, G, hd), jnp.bfloat16),
              jax.ShapeDtypeStruct((), jnp.int32))
     compiled = jax.jit(flash).lower(*specs).compile()
-    from repro.launch.dryrun import parse_collectives, peak_bytes
+    from repro.launch.dryrun import parse_collectives
     census = parse_collectives(compiled.as_text())
     out = {"kind": "flash_decode_gemma3_layer", "mesh": "16x16",
-           "peak_bytes_per_dev": peak_bytes(
-               compiled.memory_analysis()),
+           "peak_bytes_per_dev":
+               compiled.memory_analysis().peak_memory_in_bytes,
            "collectives": census, "ok": True}
     print(json.dumps(out, indent=1))
     os.makedirs("results", exist_ok=True)

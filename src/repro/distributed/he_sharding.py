@@ -18,7 +18,7 @@ Bit-exactness (the invariant `tests/test_he_sharding.py` pins):
 * the windowed matvec's per-shard partials are exact group elements;
   group products are associative and canonical Montgomery residues are
   unique, so the butterfly ⊕-combine equals the single-device
-  sequential/chunked fold bit for bit (the `ops.he_matvec_fused`
+  sequential/chunked fold bit for bit (the `ops.rns_he_matvec_fused`
   chunking argument, lifted across devices).
 * padded rows carry zero digits, which select mont(1) from the power
   table and fold to the group identity.
@@ -37,10 +37,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.crypto.bigint import Modulus, mont_mul as _lib_mont_mul, mont_one
+from repro.crypto.bigint import Modulus
 from repro.crypto.engine import CryptoEngine
 from repro.distributed.secure_ops import modmul_reduce
-from repro.distributed.shardmap_compat import shard_map
+from jax import shard_map
 
 _U32 = jnp.uint32
 
@@ -57,6 +57,10 @@ class ShardedCryptoEngine(CryptoEngine):
 
         mesh = jax.make_mesh((n_dev,), ("data",))
         eng = ShardedCryptoEngine(backend="jnp", mesh=mesh)
+
+    Meshes with `Explicit` axes (the `jax.make_mesh` default) and with
+    `Auto` axes both work: the shard_map bodies run on an `Auto` view of
+    the same devices.
 
     or equivalently ``CryptoEngine(..., mesh=mesh)``; `ShardedCryptoEngine`
     only adds the constructor-time check that a mesh is present.
@@ -113,6 +117,16 @@ def _pad_rows(x: jnp.ndarray, multiple: int) -> jnp.ndarray:
     return x
 
 
+def _auto_axes(mesh):
+    """`mesh` with every axis `Auto`.  `jax.make_mesh` gives `Explicit`
+    axes, under which slicing or indexing a batch-sharded result outside
+    the shard_map body is a sharding type error; the bodies here state
+    their layouts in in_specs/out_specs and need nothing more."""
+    from jax.sharding import AxisType, Mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
 # jitted shard_map bodies, cached on (op, engine, modulus[, window]) so
 # the Paillier hot path traces once per op/shape instead of per call
 # (engines and meshes are hashable; Modulus is keyed by its int value)
@@ -127,7 +141,7 @@ def _rowwise_fn(engine: CryptoEngine, op: str, mod: Modulus):
     if fn is not None:
         return fn
     inner = engine.single_device()
-    mesh, axis = engine.mesh, engine.mesh_axis
+    mesh, axis = _auto_axes(engine.mesh), engine.mesh_axis
     if op == "mont_mul":
         def body(a, b):
             return inner.mont_mul(a, b, mod)
@@ -174,48 +188,6 @@ def sharded_mont_exp_bits(engine: CryptoEngine, base: jnp.ndarray,
     return _sharded_rowwise(engine, "mont_exp", mod, (base, bits))
 
 
-def _windowed_partial(engine: CryptoEngine, cts: jnp.ndarray,
-                      digits: jnp.ndarray, mod: Modulus,
-                      window: int) -> jnp.ndarray:
-    """One shard's windowed matvec partial: (n_loc, L) cts ×
-    (n_loc, m, levels) digits -> (m, L) partial ⊕-product.  Kernel
-    backends run the fused kernel; the jnp backend runs the library
-    ladder (power table + per-level tree-⊕ + `window` squarings) —
-    the same group element either way."""
-    if engine.uses_kernels:
-        from repro.kernels import ops
-        return ops.he_matvec_fused(cts, digits, mod, window=window,
-                                   tile_m=engine.tile_m,
-                                   chunk_n=engine.chunk_n,
-                                   interpret=engine.interpret)
-    n, m, levels = digits.shape
-    one = mont_one(mod)
-    table = [jnp.broadcast_to(one, cts.shape), cts]
-    for _ in range(2, 1 << window):
-        table.append(_lib_mont_mul(table[-1], cts, mod))
-    table = jnp.stack(table, axis=0)                  # (2^w, n, L)
-    acc = jnp.broadcast_to(one, (m, mod.L))
-    for lvl in range(levels):
-        for _ in range(window):
-            acc = _lib_mont_mul(acc, acc, mod)
-        sel = jnp.take_along_axis(
-            table[:, :, None, :], digits[None, :, :, lvl, None], axis=0)[0]
-        prod = _tree_hom_prod(sel, mod)
-        acc = _lib_mont_mul(acc, prod, mod)
-    return acc
-
-
-def _tree_hom_prod(c: jnp.ndarray, mod: Modulus) -> jnp.ndarray:
-    """⊕-reduce axis 0 (log-depth; same schedule as protocols')."""
-    while c.shape[0] > 1:
-        half = c.shape[0] // 2
-        merged = _lib_mont_mul(c[:half], c[half:2 * half], mod)
-        if c.shape[0] % 2:
-            merged = jnp.concatenate([merged, c[2 * half:]], axis=0)
-        c = merged
-    return c[0]
-
-
 def _matvec_fn(engine: CryptoEngine, mod: Modulus, window: int):
     """Build (or fetch) the jitted shard_map body for the sharded
     windowed matvec."""
@@ -224,17 +196,18 @@ def _matvec_fn(engine: CryptoEngine, mod: Modulus, window: int):
     if fn is not None:
         return fn
     inner = engine.single_device()
-    mesh, axis = engine.mesh, engine.mesh_axis
+    mesh, axis = _auto_axes(engine.mesh), engine.mesh_axis
     size = mesh.shape[axis]
 
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(P(axis, None), P(axis, None, None)),
-        out_specs=P(axis, None, None),
-        check_vma=False)
+        out_specs=P(), check_vma=False)
     def body(cts_loc, dig_loc):
-        part = _windowed_partial(inner, cts_loc, dig_loc, mod, window)
-        return modmul_reduce(part, mod, axis, size)[None]
+        # the butterfly leaves every device with the same product, so the
+        # result is replicated: no gather of one shard's copy afterwards
+        part = inner.he_matvec_windowed(cts_loc, dig_loc, mod, window)
+        return modmul_reduce(part, mod, axis, size)
 
     fn = jax.jit(body)
     _BODY_CACHE[key] = fn
@@ -252,4 +225,4 @@ def sharded_he_matvec(engine: CryptoEngine, cts: jnp.ndarray, digits,
     size = engine.mesh.shape[engine.mesh_axis]
     cts = _pad_rows(jnp.asarray(cts, _U32), size)
     digits = _pad_rows(jnp.asarray(digits, _U32), size)
-    return _matvec_fn(engine, mod, window)(cts, digits)[0]
+    return _matvec_fn(engine, mod, window)(cts, digits)

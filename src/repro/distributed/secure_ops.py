@@ -52,7 +52,7 @@ def make_modmul_reduce_shardmap(mesh, mod: Modulus, axis_name: str):
     """shard_map wrapper: (n_shards, batch, L) global → (batch, L) product
     per shard group, replicated."""
     from jax.sharding import PartitionSpec as P
-    from repro.distributed.shardmap_compat import shard_map
+    from jax import shard_map
 
     axis_size = mesh.shape[axis_name]
 

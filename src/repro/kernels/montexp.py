@@ -1,35 +1,29 @@
-"""Fused Pallas TPU kernels for the Paillier hot path: the full
-constant-time Montgomery ladder and the windowed HE matvec, each inside
-ONE `pallas_call`.
+"""Fused Pallas TPU kernels for the Paillier hot path: the constant-time
+Montgomery ladder, the windowed HE matvec and the fixed-base table walk,
+each inside ONE `pallas_call`.
 
-`ops.mont_exp_bits` runs the ladder as 2×nbits separate `montmul_tiled`
-launches — every square and every multiply round-trips the accumulator
-through HBM.  The two kernels here keep the working set resident in
-VMEM for the whole ladder:
+Every Montgomery product is ONE RNS round (`rns.montmul_channels` traced
+inline): channel-pointwise VPU ops plus two exact f32 base-extension
+matmuls that map onto the MXU.  All three kernels work on channel states
+in the ·B domain and keep the working set resident in VMEM for the whole
+ladder / matvec / table walk; the limbs ↔ channels conversions and the
+final exact reconstruction (`rns.from_rns`) stay outside in ops.py,
+amortized over the whole walk.
 
-* `mont_exp_fused` — grid (batch/TILE_B,); blocks base (TILE_B, L),
-  bits (TILE_B, nbits), N and R mod N (1, L).  The square/select/multiply
-  loop is a `fori_loop` over nbits with two `_montmul_block` calls per
-  step; the select is a lane-wise `where`, so the ladder stays
-  constant-time (appropriate for secret exponents).  VMEM per program:
-  ~4 blocks × TILE_B × L × 4 B ≈ 0.4 MB at TILE_B=128, L=176 (2048-bit)
-  plus TILE_B × nbits bits.
+Layout rules the TPU kernel compiler imposes, which the interpreter does
+not check:
 
-* `he_matvec_fused` — Protocol 3's plaintext-matrix × ciphertext-vector
-  product, fixed-window form.  Grid (m/TILE_M,); blocks cts (n, L),
-  digits (levels, n, TILE_M) (MSB-first window digits, precomputed once
-  per batch by `protocols.EncodedFeatures`).  The kernel builds the
-  2^window power table in VMEM, then per digit level folds the selected
-  powers into a running ⊕-product and squares the accumulator `window`
-  times.  Sequential fold and the library's tree fold compute the same
-  group element, and canonical Montgomery residues are unique, so the
-  output is bit-exact vs `protocols._he_matvec_windowed`.  VMEM per
-  program: table 2^w × n × L × 4 B — the `ops.he_matvec_fused` wrapper
-  chunks n to keep this bounded (chunk outputs combine homomorphically,
-  again bit-exact).
+* no dynamic slice along the lane (last) axis — a per-step exponent bit
+  or digit is picked out of its row by a one-hot lane sum (`_pick`);
+* dynamic indices only on a ref's leading axes — the matvec power table
+  lives in a VMEM scratch ref (npow, nrows, CH) written one power at a
+  time, and the fixed-base table is indexed per level through its ref;
+* loops are `fori_loop`s so each kernel holds a few copies of the round,
+  not one per unrolled step.
 
-Both kernels reuse `montmul._montmul_block` (traced inline, so each
-kernel's IR is still self-contained when it ships to Mosaic).
+VMEM per program: the double-buffered blocks (the two (CH, CH) extension
+matrices dominate), the matvec's power table npow × n_chunk × CH × 4 B,
+and the temporaries of one RNS round; ops.py chunks n to bound the table.
 """
 from __future__ import annotations
 
@@ -38,9 +32,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.crypto import rns as rns_lib
-from repro.kernels.montmul import _montmul_block
 
 _U32 = jnp.uint32
 
@@ -48,153 +42,40 @@ DEFAULT_TILE_B = 128
 DEFAULT_TILE_M = 128
 DEFAULT_CHUNK_N = 512
 
+# Scoped-VMEM limit of every kernel here.  v5e has 128 MiB of VMEM per
+# core; the default scoped limit of 32 MiB is too small at 2048-bit keys,
+# where the v5e compiler reported scoped stacks of 47.2 MB (ladder),
+# 44.6 MB (fixed-base) and 77.0 MB (matvec at chunk 512, of which 25.2 MB
+# is its power table).  The rest is left to Mosaic's own scratch.
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 2**20)
 
-# ---------------------------------------------------------------------------
-# Fused constant-time ladder
-# ---------------------------------------------------------------------------
-
-def _exp_kernel(n0inv: int, L: int, nbits: int,
-                base_ref, bits_ref, n_ref, r1_ref, o_ref):
-    base = base_ref[...]                        # (TB, L)
-    bits = bits_ref[...]                        # (TB, nbits) MSB-first
-    n = n_ref[...]                              # (1, L)
-    acc0 = jnp.broadcast_to(r1_ref[...], base.shape)   # mont(1)
-
-    def step(i, acc):
-        acc = _montmul_block(acc, acc, n, n0inv, L)
-        mul = _montmul_block(acc, base, n, n0inv, L)
-        bit = jax.lax.dynamic_slice_in_dim(bits, i, 1, axis=1)   # (TB, 1)
-        return jnp.where(bit == 1, mul, acc)
-
-    o_ref[...] = jax.lax.fori_loop(0, nbits, step, acc0)
-
-
-@functools.partial(jax.jit, static_argnames=("n0inv", "L", "tile_b",
-                                             "interpret"))
-def mont_exp_tiled(base: jnp.ndarray, bits: jnp.ndarray, n: jnp.ndarray,
-                   r1: jnp.ndarray, *, n0inv: int, L: int,
-                   tile_b: int = DEFAULT_TILE_B,
-                   interpret: bool = True) -> jnp.ndarray:
-    """base: (batch, L) Montgomery-domain canonical; bits: (batch, nbits)
-    MSB-first.  Returns base^e in the Montgomery domain, canonical.
-    batch must be a multiple of tile_b (ops.py pads)."""
-    batch, nbits = bits.shape
-    assert base.shape == (batch, L)
-    assert batch % tile_b == 0, "pad batch to a tile multiple in ops.py"
-    grid = (batch // tile_b,)
-    return pl.pallas_call(
-        functools.partial(_exp_kernel, n0inv, L, nbits),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_b, L), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, nbits), lambda i: (i, 0)),
-            pl.BlockSpec((1, L), lambda i: (0, 0)),
-            pl.BlockSpec((1, L), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_b, L), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, L), jnp.uint32),
-        interpret=interpret,
-    )(base, bits, n.reshape(1, L), r1.reshape(1, L))
-
-
-# ---------------------------------------------------------------------------
-# Fused windowed HE matvec
-# ---------------------------------------------------------------------------
-
-def _matvec_kernel(n0inv: int, L: int, window: int, levels: int,
-                   nrows: int, cts_ref, dig_ref, n_ref, r1_ref, o_ref):
-    cts = cts_ref[...]                          # (nrows, L)
-    digs = dig_ref[...]                         # (levels, nrows, TM)
-    n = n_ref[...]                              # (1, L)
-    one = r1_ref[...]                           # (1, L)
-    TM = o_ref.shape[0]
-    npow = 1 << window
-
-    # power table c_i^j for j < 2^window: (npow, nrows, L) in VMEM
-    table = jnp.zeros((npow, nrows, L), _U32)
-    table = table.at[0].set(jnp.broadcast_to(one, (nrows, L)))
-    table = table.at[1].set(cts)
-
-    def build(j, tab):
-        prev = jax.lax.dynamic_index_in_dim(tab, j - 1, axis=0,
-                                            keepdims=False)
-        nxt = _montmul_block(prev, cts, n, n0inv, L)
-        return jax.lax.dynamic_update_index_in_dim(tab, nxt, j, axis=0)
-
-    table = jax.lax.fori_loop(2, npow, build, table)
-
-    acc = jnp.broadcast_to(one, (TM, L))
-    for lvl in range(levels):                   # static: levels ≈ 6
-        for _ in range(window):
-            acc = _montmul_block(acc, acc, n, n0inv, L)
-        dig_lvl = digs[lvl]                     # (nrows, TM)
-
-        def row(i, p):
-            di = jax.lax.dynamic_index_in_dim(dig_lvl, i, axis=0,
-                                              keepdims=False)      # (TM,)
-            row_tab = jax.lax.dynamic_index_in_dim(table, i, axis=1,
-                                                   keepdims=False)  # (npow, L)
-            # one-hot select (no gather: TPU-friendly lane-wise wheres)
-            sel = jnp.broadcast_to(one, (TM, L))
-            for j in range(1, npow):
-                sel = jnp.where((di == j)[:, None], row_tab[j][None], sel)
-            return _montmul_block(p, sel, n, n0inv, L)
-
-        prod = jax.lax.fori_loop(0, nrows, row,
-                                 jnp.broadcast_to(one, (TM, L)))
-        acc = _montmul_block(acc, prod, n, n0inv, L)
-    o_ref[...] = acc
-
-
-@functools.partial(jax.jit, static_argnames=("n0inv", "L", "window",
-                                             "tile_m", "interpret"))
-def he_matvec_tiled(cts: jnp.ndarray, digits: jnp.ndarray, n: jnp.ndarray,
-                    r1: jnp.ndarray, *, n0inv: int, L: int, window: int,
-                    tile_m: int = DEFAULT_TILE_M,
-                    interpret: bool = True) -> jnp.ndarray:
-    """cts: (nrows, L) Montgomery ciphertexts; digits: (levels, nrows, m)
-    MSB-first window digits.  Returns (m, L) ciphertexts of
-    Σ_i digit-value_i · m_i.  m must be a multiple of tile_m (ops.py
-    pads with zero digits — the padded columns fold to mont(1) and are
-    dropped)."""
-    levels, nrows, m = digits.shape
-    assert cts.shape == (nrows, L)
-    assert m % tile_m == 0, "pad m to a tile multiple in ops.py"
-    grid = (m // tile_m,)
-    return pl.pallas_call(
-        functools.partial(_matvec_kernel, n0inv, L, window, levels, nrows),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((nrows, L), lambda i: (0, 0)),
-            pl.BlockSpec((levels, nrows, tile_m), lambda i: (0, 0, i)),
-            pl.BlockSpec((1, L), lambda i: (0, 0)),
-            pl.BlockSpec((1, L), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_m, L), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, L), jnp.uint32),
-        interpret=interpret,
-    )(cts, digits, n.reshape(1, L), r1.reshape(1, L))
-
-
-# ---------------------------------------------------------------------------
-# RNS channel-domain fused kernels (the compiled pipeline — crypto/rns.py)
-# ---------------------------------------------------------------------------
-#
-# Same fusion story as above, but every Montgomery product is ONE RNS
-# round (`rns.montmul_channels` traced inline): channel-pointwise VPU ops
-# plus two exact f32 base-extension matmuls that map onto the MXU.  All
-# three kernels work on channel states in the ·B domain; the limbs ↔
-# channels conversions and the final exact reconstruction (`rns.from_rns`)
-# stay outside in ops.py, amortized over the whole ladder / matvec / table
-# walk.  Per-program VMEM at CH=166 (1024-bit n²): ladder ~4 blocks ×
-# TILE_B × CH × 4 B ≈ 0.4 MB; matvec table 2^w × n_chunk × CH × 4 B —
-# ops.py chunks n exactly as it does for the CIOS kernel.
 
 def _rns_mm(mods, tb, ta, vecs, kA, kB, ainv_r):
     return functools.partial(rns_lib.montmul_channels, mods=mods, t_b=tb,
                              t_a=ta, vecs=vecs, kA=kA, kB=kB,
-                             ainv_r=ainv_r)
+                             ainv_r=ainv_r, rem=rns_lib.mod_u28_recip)
 
+
+def _pick(rows, i):
+    """Column `i` (traced) of a (R, C) uint32 block as (R, 1): a one-hot
+    lane sum, since the kernel compiler has no dynamic lane slice."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    hit = jnp.where(lane == i, rows.astype(jnp.int32), 0)
+    return jnp.sum(hit, axis=1, keepdims=True).astype(_U32)
+
+
+def _select(d, one, powers, npow):
+    """Row-wise table lookup: powers[d] per row of the (R, 1) digits d,
+    as lane-wise wheres (no gather)."""
+    sel = jnp.broadcast_to(one, (d.shape[0], one.shape[-1]))
+    for j in range(1, npow):
+        sel = jnp.where(d == j, powers[j:j + 1], sel)
+    return sel
+
+
+# ---------------------------------------------------------------------------
+# Constant-time ladder
+# ---------------------------------------------------------------------------
 
 def _rns_exp_kernel(kA: int, kB: int, ainv_r: int, nbits: int,
                     u_ref, bits_ref, mods_ref, tb_ref, ta_ref, vecs_ref,
@@ -208,8 +89,7 @@ def _rns_exp_kernel(kA: int, kB: int, ainv_r: int, nbits: int,
     def step(i, acc):
         acc = mm(acc, acc)
         mul = mm(acc, u)
-        bit = jax.lax.dynamic_slice_in_dim(bits, i, 1, axis=1)   # (TB, 1)
-        return jnp.where(bit == 1, mul, acc)
+        return jnp.where(_pick(bits, i) == 1, mul, acc)
 
     acc = jax.lax.fori_loop(0, nbits, step, acc0)
     o_ref[...] = mm(acc, exit_ref[...])          # v^e·B ↦ v^e·R
@@ -221,9 +101,8 @@ def rns_mont_exp_tiled(u: jnp.ndarray, bits: jnp.ndarray,
                        mods: jnp.ndarray, t_b: jnp.ndarray,
                        t_a: jnp.ndarray, vecs: jnp.ndarray,
                        one: jnp.ndarray, exitc: jnp.ndarray, *,
-                       kA: int, kB: int, ainv_r: int,
-                       tile_b: int = DEFAULT_TILE_B,
-                       interpret: bool = True) -> jnp.ndarray:
+                       kA: int, kB: int, ainv_r: int, interpret: bool,
+                       tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Fused constant-time ladder on channel states.  u: (batch, CH) the
     base via `rns.to_rns_scaled`; bits: (batch, nbits) MSB-first.
     Returns the channel state of base^e·R (< (kB+2)·N) — finish with
@@ -247,57 +126,50 @@ def rns_mont_exp_tiled(u: jnp.ndarray, bits: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tile_b, CH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, CH), jnp.uint32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="rns_mont_exp",
     )(u, bits, mods.reshape(1, CH), t_b, t_a, vecs,
       one.reshape(1, CH), exitc.reshape(1, CH))
 
 
+# ---------------------------------------------------------------------------
+# Windowed HE matvec
+# ---------------------------------------------------------------------------
+
 def _rns_matvec_kernel(kA: int, kB: int, ainv_r: int, window: int,
                        levels: int, nrows: int,
                        u_ref, dig_ref, mods_ref, tb_ref, ta_ref, vecs_ref,
-                       one_ref, o_ref):
-    u = u_ref[...]                               # (nrows, CH) scaled cts
-    digs = dig_ref[...]                          # (levels, nrows, TM)
+                       one_ref, o_ref, table_ref):
     one = one_ref[...]                           # (1, CH)
     mm = _rns_mm(mods_ref[...], tb_ref[...], ta_ref[...], vecs_ref[...],
                  kA, kB, ainv_r)
-    TM = o_ref.shape[0]
-    CH = u.shape[1]
+    TM, CH = o_ref.shape
     npow = 1 << window
 
     # power table c_i^j·B for j < 2^window: (npow, nrows, CH) in VMEM
-    table = jnp.zeros((npow, nrows, CH), _U32)
-    table = table.at[0].set(jnp.broadcast_to(one, (nrows, CH)))
-    table = table.at[1].set(u)
+    u = u_ref[...]                               # (nrows, CH) scaled cts
+    table_ref[0] = jnp.broadcast_to(one, (nrows, CH))
+    table_ref[1] = u
 
-    def build(j, tab):
-        prev = jax.lax.dynamic_index_in_dim(tab, j - 1, axis=0,
-                                            keepdims=False)
-        return jax.lax.dynamic_update_index_in_dim(tab, mm(prev, u), j,
-                                                   axis=0)
+    def build(j, carry):
+        table_ref[j] = mm(table_ref[j - 1], u)
+        return carry
 
-    table = jax.lax.fori_loop(2, npow, build, table)
+    jax.lax.fori_loop(2, npow, build, 0)
+    one_tm = jnp.broadcast_to(one, (TM, CH))
 
-    acc = jnp.broadcast_to(one, (TM, CH))
-    for lvl in range(levels):                    # static: levels ≈ 6
-        for _ in range(window):
-            acc = mm(acc, acc)
-        dig_lvl = digs[lvl]                      # (nrows, TM)
+    def level(lvl, acc):
+        acc = jax.lax.fori_loop(0, window, lambda _, a: mm(a, a), acc)
+        dig_lvl = dig_ref[lvl]                   # (TM, nrows)
 
         def row(i, p):
-            di = jax.lax.dynamic_index_in_dim(dig_lvl, i, axis=0,
-                                              keepdims=False)       # (TM,)
-            row_tab = jax.lax.dynamic_index_in_dim(table, i, axis=1,
-                                                   keepdims=False)  # (npow, CH)
-            sel = jnp.broadcast_to(one, (TM, CH))
-            for j in range(1, npow):
-                sel = jnp.where((di == j)[:, None], row_tab[j][None], sel)
-            return mm(p, sel)
+            powers = table_ref[:, pl.ds(i, 1), :][:, 0, :]   # (npow, CH)
+            return mm(p, _select(_pick(dig_lvl, i), one, powers, npow))
 
-        prod = jax.lax.fori_loop(0, nrows, row,
-                                 jnp.broadcast_to(one, (TM, CH)))
-        acc = mm(acc, prod)
-    o_ref[...] = acc                             # ·B domain — exit outside
+        return mm(acc, jax.lax.fori_loop(0, nrows, row, one_tm))
+
+    o_ref[...] = jax.lax.fori_loop(0, levels, level, one_tm)   # ·B domain
 
 
 @functools.partial(jax.jit, static_argnames=("kA", "kB", "ainv_r",
@@ -307,16 +179,16 @@ def rns_he_matvec_tiled(u: jnp.ndarray, digits: jnp.ndarray,
                         mods: jnp.ndarray, t_b: jnp.ndarray,
                         t_a: jnp.ndarray, vecs: jnp.ndarray,
                         one: jnp.ndarray, *, kA: int, kB: int,
-                        ainv_r: int, window: int,
-                        tile_m: int = DEFAULT_TILE_M,
-                        interpret: bool = True) -> jnp.ndarray:
+                        ainv_r: int, window: int, interpret: bool,
+                        tile_m: int = DEFAULT_TILE_M) -> jnp.ndarray:
     """Fused windowed HE matvec on channel states.  u: (nrows, CH) the
-    ciphertexts via `rns.to_rns_scaled`; digits: (levels, nrows, m)
-    MSB-first window digits.  Returns (m, CH) ·B-domain channel states of
-    the column products — chunk-⊕, exit, and `rns.from_rns` happen in
-    ops.py."""
-    levels, nrows, m = digits.shape
+    ciphertexts via `rns.to_rns_scaled`; digits: (levels, m, nrows)
+    MSB-first window digits, output column on the sublane axis.  Returns
+    (m, CH) ·B-domain channel states of the column products — chunk-⊕,
+    exit, and `rns.from_rns` happen in ops.py."""
+    levels, m, nrows = digits.shape
     CH = u.shape[1]
+    npow = 1 << window
     assert m % tile_m == 0, "pad m to a tile multiple in ops.py"
     grid = (m // tile_m,)
     return pl.pallas_call(
@@ -325,7 +197,7 @@ def rns_he_matvec_tiled(u: jnp.ndarray, digits: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((nrows, CH), lambda i: (0, 0)),
-            pl.BlockSpec((levels, nrows, tile_m), lambda i: (0, 0, i)),
+            pl.BlockSpec((levels, tile_m, nrows), lambda i: (0, i, 0)),
             pl.BlockSpec((1, CH), lambda i: (0, 0)),
             pl.BlockSpec(t_b.shape, lambda i: (0, 0)),
             pl.BlockSpec(t_a.shape, lambda i: (0, 0)),
@@ -334,35 +206,36 @@ def rns_he_matvec_tiled(u: jnp.ndarray, digits: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tile_m, CH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, CH), jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((npow, nrows, CH), jnp.uint32)],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="rns_he_matvec",
     )(u, digits, mods.reshape(1, CH), t_b, t_a, vecs, one.reshape(1, CH))
 
+
+# ---------------------------------------------------------------------------
+# Fixed-base table walk
+# ---------------------------------------------------------------------------
 
 def _rns_fixb_kernel(kA: int, kB: int, ainv_r: int, window: int,
                      levels: int,
                      tab_ref, dig_ref, mods_ref, tb_ref, ta_ref, vecs_ref,
                      one_ref, exit_ref, o_ref):
-    tab = tab_ref[...]                           # (levels, npow, CH)
     digs = dig_ref[...]                          # (TB, levels) LSB-first
     one = one_ref[...]                           # (1, CH)
     mm = _rns_mm(mods_ref[...], tb_ref[...], ta_ref[...], vecs_ref[...],
                  kA, kB, ainv_r)
     TB = digs.shape[0]
-    CH = tab.shape[-1]
+    CH = one.shape[-1]
     npow = 1 << window
-    acc0 = jnp.broadcast_to(one, (TB, CH))
 
     def step(lvl, acc):
-        t_lvl = jax.lax.dynamic_index_in_dim(tab, lvl, axis=0,
-                                             keepdims=False)  # (npow, CH)
-        d = jax.lax.dynamic_slice_in_dim(digs, lvl, 1, axis=1)  # (TB, 1)
         # digit 0 selects table[lvl][0] = one — mm(acc, one) is identity
-        sel = jnp.broadcast_to(one, (TB, CH))
-        for j in range(1, npow):
-            sel = jnp.where(d == j, t_lvl[j][None], sel)
+        sel = _select(_pick(digs, lvl), one, tab_ref[lvl], npow)
         return mm(acc, sel)
 
-    acc = jax.lax.fori_loop(0, levels, step, acc0)
+    acc = jax.lax.fori_loop(0, levels, step,
+                            jnp.broadcast_to(one, (TB, CH)))
     o_ref[...] = mm(acc, exit_ref[...])          # h^e·B ↦ h^e·R
 
 
@@ -374,8 +247,8 @@ def rns_fixed_base_tiled(table: jnp.ndarray, digits: jnp.ndarray,
                          t_a: jnp.ndarray, vecs: jnp.ndarray,
                          one: jnp.ndarray, exitc: jnp.ndarray, *,
                          kA: int, kB: int, ainv_r: int, window: int,
-                         tile_b: int = DEFAULT_TILE_B,
-                         interpret: bool = True) -> jnp.ndarray:
+                         interpret: bool,
+                         tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """Fixed-base windowed exponentiation from a prepared ·B-domain
     table (levels, 2^window, CH); digits: (batch, levels) LSB-first
     base-2^window digits of the exponent.  Returns the channel state of
@@ -404,6 +277,8 @@ def rns_fixed_base_tiled(table: jnp.ndarray, digits: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tile_b, CH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, CH), jnp.uint32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="rns_fixed_base",
     )(table, digits, mods.reshape(1, CH), t_b, t_a, vecs,
       one.reshape(1, CH), exitc.reshape(1, CH))

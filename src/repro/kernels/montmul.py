@@ -1,23 +1,21 @@
-"""Pallas TPU kernel: batched Montgomery modular multiplication.
+"""Pallas TPU kernel: batched RNS Montgomery modular multiplication.
 
 The compute hot spot of EFMVFL is Paillier arithmetic — Protocol 3's
 plaintext-matrix × ciphertext-vector product is millions of Montgomery
 products over 2048-bit residues.  This kernel evaluates a *batch* of
-Montgomery products entirely in VMEM:
+Montgomery products on residue-channel states (crypto/rns.py) in VMEM:
 
   grid     : (batch / TILE_B,)
-  blocks   : A, B, out — (TILE_B, L) uint32 limb planes in VMEM
-             N          — (1, L) broadcast to every program
-  compute  : the radix-2^12 CIOS loop (see crypto/bigint.py) — limb
-             products ≤ 2^24 accumulate in native int32/uint32 vector
-             lanes; one lazy-carry pass per round keeps limbs < 2^16.
+  blocks   : x, y, out — (TILE_B, CH) uint32 channel states
+             mods (1, CH), t_b / t_a (CH, CH), vecs (6, CH) — broadcast
+             to every program
+  compute  : ONE round of channel-pointwise math plus two exact f32
+             matmuls (the base extensions) — the shape the MXU wants.
 
-TPU adaptation notes (DESIGN.md §3): word-serial bignum code (gmp-style)
-has no TPU analogue — no 64-bit multiplier, no carry flag.  Radix-2^12
-limb vectors turn the whole inner loop into 8-lane-friendly u32 FMAs with
-*no cross-lane communication* except the final carry sweep, and the batch
-dimension maps onto the VPU sublanes.  VMEM budget per program:
-3 blocks × TILE_B × (L+1) × 4 B ≈ 0.4 MB at TILE_B=128, L=176 (2048-bit).
+The body is `rns.montmul_channels` traced inline, so the kernel and the
+jnp library path are the same arithmetic by construction.  Conversions
+limbs ↔ channels stay outside the kernel (ops.py), amortized across
+ladder / matvec steps.
 """
 from __future__ import annotations
 
@@ -28,108 +26,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.crypto import rns as rns_lib
-
-LIMB_BITS = 12
-MASK = (1 << LIMB_BITS) - 1
-_U32 = jnp.uint32
+from repro.kernels.montexp import COMPILER_PARAMS
 
 DEFAULT_TILE_B = 128
 
-
-def _montmul_block(a, b, n, n0inv: int, L: int):
-    """CIOS Montgomery product on a (TB, L) block.  Shared only by
-    kernel bodies (this one and the fused ladders in montexp.py) — it is
-    traced inline, so each kernel's IR is still self-contained when it
-    ships to Mosaic."""
-    TB = a.shape[0]
-    t = jnp.zeros((TB, L + 1), _U32)
-
-    def round_fn(i, t):
-        ai = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=1)      # (TB, 1)
-        t = t.at[:, :L].add(ai * b)
-        m = (t[:, 0] * _U32(n0inv)) & MASK
-        t = t.at[:, :L].add(m[:, None] * n)
-        carry0 = t[:, 0] >> LIMB_BITS
-        t = jnp.concatenate([t[:, 1:], jnp.zeros((TB, 1), _U32)], axis=1)
-        t = t.at[:, 0].add(carry0)
-        # one-shot lazy carry: keeps limbs < 2^16 (exact, value-preserving)
-        low = t & MASK
-        hi = t >> LIMB_BITS
-        return low + jnp.concatenate(
-            [jnp.zeros((TB, 1), _U32), hi[:, :-1]], axis=1)
-
-    t = jax.lax.fori_loop(0, L, round_fn, t)
-
-    # exact normalization (sequential carry over L+1 limbs)
-    def sweep(i, st):
-        t, c = st
-        v = t[:, i] + c
-        return t.at[:, i].set(v & MASK), v >> LIMB_BITS
-
-    t, _ = jax.lax.fori_loop(0, L + 1, sweep, (t, jnp.zeros((TB,), _U32)))
-
-    # conditional subtract N (t < 2N): compute t - N with borrow, select
-    npad = jnp.concatenate([n, jnp.zeros((1, 1), _U32)], axis=1)  # (1, L+1)
-
-    def sub_step(i, st):
-        d, borrow = st
-        v = t[:, i] + _U32(1 << LIMB_BITS) - npad[0, i] - borrow
-        return d.at[:, i].set(v & MASK), _U32(1) - (v >> LIMB_BITS)
-
-    d0 = jnp.zeros_like(t)
-    d, borrow = jax.lax.fori_loop(0, L + 1, sub_step,
-                                  (d0, jnp.zeros((TB,), _U32)))
-    keep_t = (borrow == 1)[:, None]
-    return jnp.where(keep_t, t, d)[:, :L]
-
-
-def _kernel(n0inv: int, L: int, a_ref, b_ref, n_ref, o_ref):
-    o_ref[...] = _montmul_block(a_ref[...], b_ref[...], n_ref[...],
-                                n0inv, L)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("n0inv", "L", "tile_b", "interpret"))
-def montmul_tiled(a: jnp.ndarray, b: jnp.ndarray, n: jnp.ndarray,
-                  *, n0inv: int, L: int, tile_b: int = DEFAULT_TILE_B,
-                  interpret: bool = True) -> jnp.ndarray:
-    """a, b: (batch, L) canonical limbs (< N); n: (L,).  Returns
-    a·b·R^{-1} mod N, canonical.  batch must be a multiple of tile_b
-    (ops.py pads)."""
-    batch = a.shape[0]
-    assert batch % tile_b == 0, "pad batch to a tile multiple in ops.py"
-    grid = (batch // tile_b,)
-    return pl.pallas_call(
-        functools.partial(_kernel, n0inv, L),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_b, L), lambda i: (i, 0)),
-            pl.BlockSpec((tile_b, L), lambda i: (i, 0)),
-            pl.BlockSpec((1, L), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((tile_b, L), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch, L), jnp.uint32),
-        interpret=interpret,
-    )(a, b, n.reshape(1, L))
-
-
-# ---------------------------------------------------------------------------
-# RNS channel-domain kernel — the compiled pipeline (crypto/rns.py)
-# ---------------------------------------------------------------------------
-#
-# Where the CIOS kernel above runs L sequential carry-coupled rounds, the
-# RNS kernel is ONE round of channel-pointwise math plus two exact f32
-# matmuls (the base extensions) — the shape the MXU wants.  The body is
-# `rns.montmul_channels` traced inline, so the kernel and the jnp library
-# path are the same arithmetic by construction.  Conversions limbs ↔
-# channels stay outside the kernel (ops.py), amortized across ladder /
-# matvec steps.
 
 def _rns_kernel(kA: int, kB: int, ainv_r: int,
                 x_ref, y_ref, mods_ref, tb_ref, ta_ref, vecs_ref, o_ref):
     o_ref[...] = rns_lib.montmul_channels(
         x_ref[...], y_ref[...], mods_ref[...], tb_ref[...], ta_ref[...],
-        vecs_ref[...], kA=kA, kB=kB, ainv_r=ainv_r)
+        vecs_ref[...], kA=kA, kB=kB, ainv_r=ainv_r,
+        rem=rns_lib.mod_u28_recip)
 
 
 @functools.partial(jax.jit, static_argnames=("kA", "kB", "ainv_r",
@@ -137,8 +44,8 @@ def _rns_kernel(kA: int, kB: int, ainv_r: int,
 def rns_montmul_tiled(x: jnp.ndarray, y: jnp.ndarray, mods: jnp.ndarray,
                       t_b: jnp.ndarray, t_a: jnp.ndarray,
                       vecs: jnp.ndarray, *, kA: int, kB: int, ainv_r: int,
-                      tile_b: int = DEFAULT_TILE_B,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool,
+                      tile_b: int = DEFAULT_TILE_B) -> jnp.ndarray:
     """x, y: (batch, CH) channel states < (kB+2)·N (y usually entered via
     `rns.to_rns_scaled`).  Returns the channel state of x·y·B⁻¹, same
     bound.  batch must be a multiple of tile_b (ops.py pads)."""
@@ -158,5 +65,7 @@ def rns_montmul_tiled(x: jnp.ndarray, y: jnp.ndarray, mods: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((tile_b, CH), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch, CH), jnp.uint32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="rns_montmul",
     )(x, y, mods.reshape(1, CH), t_b, t_a, vecs)

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels: padding, K-chunking,
 batch flattening, and drop-in integration points for the crypto layer.
 
-`interpret` defaults to True (this container is CPU); on real TPU pass
-interpret=False — the kernels are written against BlockSpec VMEM tiling.
+`interpret` is a required keyword everywhere: True runs the kernel body
+through the Pallas interpreter (CPU parity tests), False compiles it for
+the TPU.  Callers pass `CryptoEngine.interpret`, so a kernel never runs
+interpreted on the chip by default.
 """
 from __future__ import annotations
 
@@ -22,110 +24,9 @@ from repro.kernels import ring_matmul as ringmm_k
 _U32 = jnp.uint32
 
 
-def montmul(a: jnp.ndarray, b: jnp.ndarray, mod: Modulus, *,
-            tile_b: int = montmul_k.DEFAULT_TILE_B,
-            interpret: bool = True) -> jnp.ndarray:
-    """Batched Montgomery product via the Pallas kernel.  Accepts any
-    leading batch shape; broadcasts a against b; pads to the tile."""
-    a, b = jnp.broadcast_arrays(a.astype(_U32), b.astype(_U32))
-    bshape = a.shape[:-1]
-    L = mod.L
-    flat = int(np.prod(bshape)) if bshape else 1
-    a2 = a.reshape(flat, L)
-    b2 = b.reshape(flat, L)
-    pad = (-flat) % tile_b
-    if pad:
-        a2 = jnp.concatenate([a2, jnp.zeros((pad, L), _U32)], 0)
-        b2 = jnp.concatenate([b2, jnp.zeros((pad, L), _U32)], 0)
-    out = montmul_k.montmul_tiled(
-        a2, b2, jnp.asarray(mod.limbs, _U32),
-        n0inv=mod.n0inv, L=L, tile_b=tile_b, interpret=interpret)
-    return out[:flat].reshape(bshape + (L,))
-
-
-def mont_exp_bits(base: jnp.ndarray, bits: jnp.ndarray, mod: Modulus, *,
-                  interpret: bool = True) -> jnp.ndarray:
-    """Per-step kernel ladder (same contract as bigint.mont_exp_bits):
-    2×nbits separate `montmul_tiled` launches — kept as the baseline the
-    fused kernel is benchmarked against (kernel_bench)."""
-    bshape = jnp.broadcast_shapes(base.shape[:-1], bits.shape[:-1])
-    base = jnp.broadcast_to(base, bshape + base.shape[-1:])
-    bits = jnp.broadcast_to(bits.astype(_U32), bshape + bits.shape[-1:])
-    acc0 = jnp.broadcast_to(jnp.asarray(mod.r1, _U32), base.shape)
-
-    def step(acc, bit):
-        acc = montmul(acc, acc, mod, interpret=interpret)
-        mul = montmul(acc, base, mod, interpret=interpret)
-        return jnp.where(bit[..., None] == 1, mul, acc), None
-
-    acc, _ = jax.lax.scan(step, acc0, jnp.moveaxis(bits, -1, 0))
-    return acc
-
-
-def mont_exp_fused(base: jnp.ndarray, bits: jnp.ndarray, mod: Modulus, *,
-                  tile_b: int = montexp_k.DEFAULT_TILE_B,
-                  interpret: bool = True) -> jnp.ndarray:
-    """Fused-ladder kernel (same contract as bigint.mont_exp_bits): the
-    whole constant-time square-and-multiply loop in ONE pallas_call with
-    the accumulator resident in VMEM."""
-    base = jnp.asarray(base, _U32)
-    bits = jnp.asarray(bits, _U32)
-    bshape = jnp.broadcast_shapes(base.shape[:-1], bits.shape[:-1])
-    L = mod.L
-    nbits = bits.shape[-1]
-    base = jnp.broadcast_to(base, bshape + (L,))
-    bits = jnp.broadcast_to(bits, bshape + (nbits,))
-    flat = int(np.prod(bshape)) if bshape else 1
-    b2 = base.reshape(flat, L)
-    e2 = bits.reshape(flat, nbits)
-    tb = min(tile_b, max(flat, 1))
-    pad = (-flat) % tb
-    if pad:
-        b2 = jnp.concatenate([b2, jnp.zeros((pad, L), _U32)], 0)
-        e2 = jnp.concatenate([e2, jnp.zeros((pad, nbits), _U32)], 0)
-    out = montexp_k.mont_exp_tiled(
-        b2, e2, jnp.asarray(mod.limbs, _U32), jnp.asarray(mod.r1, _U32),
-        n0inv=mod.n0inv, L=L, tile_b=tb, interpret=interpret)
-    return out[:flat].reshape(bshape + (L,))
-
-
-def he_matvec_fused(cts: jnp.ndarray, digits: jnp.ndarray, mod: Modulus, *,
-                    window: int,
-                    tile_m: int = montexp_k.DEFAULT_TILE_M,
-                    chunk_n: int = montexp_k.DEFAULT_CHUNK_N,
-                    interpret: bool = True) -> jnp.ndarray:
-    """Fused windowed HE matvec: cts (n, L) Montgomery ciphertexts,
-    digits (n, m, levels) MSB-first window digits (the EncodedFeatures
-    layout).  Returns (m, L) ciphertexts of Σ_i exps[i,j]·m_i, bit-exact
-    vs protocols' windowed library path.  n is chunked to bound the
-    in-kernel power table's VMEM footprint; chunk outputs combine with a
-    homomorphic ⊕ (an exact group product, so chunking preserves
-    bit-exactness)."""
-    cts = jnp.asarray(cts, _U32)
-    digits = jnp.asarray(digits, _U32)
-    n, m, levels = digits.shape
-    L = mod.L
-    tm = min(tile_m, max(m, 1))
-    pad_m = (-m) % tm
-    dt = jnp.moveaxis(digits, -1, 0)            # (levels, n, m)
-    if pad_m:
-        dt = jnp.concatenate(
-            [dt, jnp.zeros((levels, n, pad_m), _U32)], axis=-1)
-    out = None
-    for n0 in range(0, n, chunk_n):
-        n1 = min(n, n0 + chunk_n)
-        part = montexp_k.he_matvec_tiled(
-            cts[n0:n1], dt[:, n0:n1, :], jnp.asarray(mod.limbs, _U32),
-            jnp.asarray(mod.r1, _U32), n0inv=mod.n0inv, L=L,
-            window=window, tile_m=tm, interpret=interpret)
-        out = part if out is None else montmul(out, part, mod,
-                                               interpret=interpret)
-    return out[:m]
-
-
 def ring_matmul(a: R64, b: R64, *, tm: int = ringmm_k.DEFAULT_TM,
                 tn: int = ringmm_k.DEFAULT_TN,
-                interpret: bool = True) -> R64:
+                interpret: bool) -> R64:
     """(M, K) @ (K, N) over Z_2^64 via the limb-MXU kernel.  Pads M/N to
     tiles and chunks K at the exactness bound."""
     M, K = a.lo.shape
@@ -155,7 +56,7 @@ def ring_matmul(a: R64, b: R64, *, tm: int = ringmm_k.DEFAULT_TM,
 # RNS pipeline wrappers (kernels/montmul.py + montexp.py channel kernels)
 # ---------------------------------------------------------------------------
 #
-# Same public contracts as the CIOS wrappers above (canonical radix-2^12
+# Same public contracts as the `bigint` library ops (canonical radix-2^12
 # limbs in, canonical limbs out, bit-exact vs the bigint oracle), but the
 # kernel-resident representation is the RNS channel state of
 # crypto/rns.py.  Conversions run outside the pallas_call — one exact
@@ -191,10 +92,10 @@ def _rns_montmul_flat(ctx, a2, b2, *, tile_b, interpret):
 
 
 def rns_montmul(a: jnp.ndarray, b: jnp.ndarray, mod: Modulus, *,
-                tile_b: int = montmul_k.DEFAULT_TILE_B,
-                interpret: bool = True) -> jnp.ndarray:
+                interpret: bool,
+                tile_b: int = montmul_k.DEFAULT_TILE_B) -> jnp.ndarray:
     """Batched Montgomery product via the RNS channel kernel — drop-in
-    peer of `montmul` (CIOS) and `bigint.mont_mul`."""
+    peer of `bigint.mont_mul`."""
     a, b = jnp.broadcast_arrays(a.astype(_U32), b.astype(_U32))
     bshape = a.shape[:-1]
     ctx = rns.for_modulus(mod)
@@ -220,10 +121,10 @@ def _rns_exp_flat(ctx, b2, e2, *, tile_b, interpret):
 
 def rns_mont_exp_fused(base: jnp.ndarray, bits: jnp.ndarray,
                        mod: Modulus, *,
-                       tile_b: int = montexp_k.DEFAULT_TILE_B,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool,
+                       tile_b: int = montexp_k.DEFAULT_TILE_B) -> jnp.ndarray:
     """Fused constant-time ladder via the RNS kernel (peer of
-    `mont_exp_fused` / `bigint.mont_exp_bits`)."""
+    `bigint.mont_exp_bits`)."""
     base = jnp.asarray(base, _U32)
     bits = jnp.asarray(bits, _U32)
     bshape = jnp.broadcast_shapes(base.shape[:-1], bits.shape[:-1])
@@ -251,7 +152,7 @@ def _rns_matvec_flat(ctx, cts, dt, *, window, tile_m, chunk_n, interpret):
     for n0 in range(0, n, chunk_n):
         n1 = min(n, n0 + chunk_n)
         part = montexp_k.rns_he_matvec_tiled(
-            u[n0:n1], dt[:, n0:n1, :], mods, t_b, t_a, vecs, one,
+            u[n0:n1], dt[:, :, n0:n1], mods, t_b, t_a, vecs, one,
             kA=ctx.kA, kB=ctx.kB, ainv_r=ctx.ainv_r, window=window,
             tile_m=tile_m, interpret=interpret)
         # chunk-⊕ in the ·B domain: one extra RNS round per chunk
@@ -262,12 +163,12 @@ def _rns_matvec_flat(ctx, cts, dt, *, window, tile_m, chunk_n, interpret):
 
 
 def rns_he_matvec_fused(cts: jnp.ndarray, digits: jnp.ndarray,
-                        mod: Modulus, *, window: int,
+                        mod: Modulus, *, window: int, interpret: bool,
                         tile_m: int = montexp_k.DEFAULT_TILE_M,
-                        chunk_n: int = montexp_k.DEFAULT_CHUNK_N,
-                        interpret: bool = True) -> jnp.ndarray:
+                        chunk_n: int = montexp_k.DEFAULT_CHUNK_N
+                        ) -> jnp.ndarray:
     """Fused windowed HE matvec via the RNS kernel (peer of
-    `he_matvec_fused` / `protocols._he_matvec_windowed`): cts (n, L)
+    `protocols._he_matvec_windowed`): cts (n, L)
     Montgomery ciphertexts, digits (n, m, levels) MSB-first window
     digits → (m, L) canonical ciphertexts of Σ_i exps[i,j]·m_i."""
     cts = jnp.asarray(cts, _U32)
@@ -276,10 +177,10 @@ def rns_he_matvec_fused(cts: jnp.ndarray, digits: jnp.ndarray,
     ctx = rns.for_modulus(mod)
     tm = min(tile_m, max(m, 1))
     pad_m = (-m) % tm
-    dt = jnp.moveaxis(digits, -1, 0)            # (levels, n, m)
+    dt = jnp.transpose(digits, (2, 1, 0))       # (levels, m, n)
     if pad_m:
         dt = jnp.concatenate(
-            [dt, jnp.zeros((levels, n, pad_m), _U32)], axis=-1)
+            [dt, jnp.zeros((levels, pad_m, n), _U32)], axis=1)
     out = _rns_matvec_flat(ctx, cts, dt, window=window, tile_m=tm,
                            chunk_n=chunk_n, interpret=interpret)
     return out[:m]
@@ -298,9 +199,9 @@ def _rns_fixb_flat(ctx, table, d2, *, window, tile_b, interpret):
 
 
 def rns_fixed_base_fused(table: jnp.ndarray, digits: jnp.ndarray,
-                         mod: Modulus, *, window: int,
-                         tile_b: int = montexp_k.DEFAULT_TILE_B,
-                         interpret: bool = True) -> jnp.ndarray:
+                         mod: Modulus, *, window: int, interpret: bool,
+                         tile_b: int = montexp_k.DEFAULT_TILE_B
+                         ) -> jnp.ndarray:
     """Fixed-base windowed exponentiation via the RNS kernel from a
     prepared ·B-domain table (levels, 2^window, CH) — the kernel twin of
     `rns.fixed_base_exp`.  digits: (..., levels) LSB-first base-2^window

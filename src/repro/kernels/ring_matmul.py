@@ -79,8 +79,8 @@ def _kernel(a_hi_ref, a_lo_ref, b_hi_ref, b_lo_ref, o_hi_ref, o_lo_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tn", "interpret"))
-def ring_matmul_tiled(a_hi, a_lo, b_hi, b_lo, *, tm: int = DEFAULT_TM,
-                      tn: int = DEFAULT_TN, interpret: bool = True):
+def ring_matmul_tiled(a_hi, a_lo, b_hi, b_lo, *, interpret: bool,
+                      tm: int = DEFAULT_TM, tn: int = DEFAULT_TN):
     """(M, K) × (K, N) over Z_2^64; M % tm == N % tn == 0, K ≤ 2^15
     (ops.py handles padding and K-chunking)."""
     M, K = a_hi.shape

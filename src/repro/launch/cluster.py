@@ -14,6 +14,11 @@ selection — asserted by `tests/test_runtime_parity.py` — and the
 per-tag *measured* payload bytes (actual encoded frames) equal the
 analytic `wire_bytes()` accounting exactly.
 
+Device ownership: a chip belongs to one process, and the conductor (or
+the program that started it) may already hold it.  So every party
+process is told its JAX platform, `PARTY_PLATFORM`, in its spawn
+arguments, pins it before its first JAX computation, and prints it.
+
 Crash recovery: with `checkpoint_dir` + `cfg.checkpoint_every`, every
 party durably checkpoints its own state slice, and
 `train_vfl_socket_resilient` supervises the run — on any party loss it
@@ -50,6 +55,11 @@ from repro.runtime.netparty import CONDUCTOR, IO_TIMEOUT_S
 from repro.runtime.policy import RetryPolicy
 from repro.runtime.scheduler import mask_bound_bits, validate_key_bits
 from repro.runtime.transport import SocketTransport
+
+
+#: JAX platform of every party process: the host CPU, since the process
+#: that spawns the parties may own the chip
+PARTY_PLATFORM = "cpu"
 
 
 class ClusterError(RuntimeError):
@@ -173,7 +183,7 @@ class SocketCluster:
             proc = ctx.Process(
                 target=netparty.run_party_server,
                 args=(p.name, np.asarray(p.X, np.float64), y, ready,
-                      self.host, self.checkpoint_dir, wire),
+                      PARTY_PLATFORM, self.host, self.checkpoint_dir, wire),
                 name=f"vfl-party-{p.name}", daemon=True)
             proc.start()
             self.procs[p.name] = proc
@@ -695,6 +705,9 @@ def train_vfl_socket_resilient(parties: Sequence, y: np.ndarray, cfg,
 def main() -> None:
     from repro.core.trainer import PartyData, VFLConfig
     from repro.data import synthetic, vertical
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--glm", default="logistic",

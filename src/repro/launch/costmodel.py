@@ -29,15 +29,6 @@ import dataclasses
 from repro.configs.base import ModelConfig, ShapeCell
 
 
-def xla_cost_analysis(compiled) -> dict:
-    """compiled.cost_analysis() as a dict across jax versions (older
-    releases return a one-element list of per-computation dicts)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
-
-
 BF16 = 2
 F32 = 4
 ACT_TENSORS_PER_LAYER = 8     # saved/streamed activation tensors per layer

@@ -44,17 +44,6 @@ _COLL_RE = re.compile(
     r"(-start|-done)?\(")
 
 
-def peak_bytes(ma) -> int:
-    """Peak device memory from a CompiledMemoryStats, tolerating jax
-    versions that don't expose `peak_memory_in_bytes` (fall back to
-    args + outputs + temps — the steady-state resident set)."""
-    peak = getattr(ma, "peak_memory_in_bytes", None)
-    if peak:
-        return int(peak)
-    return int(ma.argument_size_in_bytes + ma.output_size_in_bytes
-               + ma.temp_size_in_bytes)
-
-
 def parse_collectives(hlo_text: str) -> dict:
     """Sum per-device result bytes of every collective op in the SPMD
     module, by op kind ('-done' halves of async pairs are skipped so
@@ -148,7 +137,7 @@ def run_cell(arch: str, cell_name: str, mesh, *, smoke: bool = False,
     compiled = lowered.compile()
     t_compile = time.time() - t0
     ma = compiled.memory_analysis()
-    ca = costmodel.xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     n_dev = mesh.devices.size
     costs = costmodel.cell_costs(cfg, cell, mesh)
@@ -161,7 +150,7 @@ def run_cell(arch: str, cell_name: str, mesh, *, smoke: bool = False,
         "n_devices": int(n_dev),
         "ok": True,
         "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
-        "peak_bytes_per_dev": peak_bytes(ma),
+        "peak_bytes_per_dev": ma.peak_memory_in_bytes,
         "arg_bytes_per_dev": int(ma.argument_size_in_bytes),
         "temp_bytes_per_dev": int(ma.temp_size_in_bytes),
         "output_bytes_per_dev": int(ma.output_size_in_bytes),
@@ -194,6 +183,8 @@ def run_cell(arch: str, cell_name: str, mesh, *, smoke: bool = False,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--cell", default=None)

@@ -7,17 +7,10 @@ dryrun.py forces 512 host devices — before any jax import).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                   # newer jax: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # pinned toolchain: Auto is implicit
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

@@ -48,7 +48,7 @@ import time              # noqa: E402
 import jax               # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-from repro.distributed.shardmap_compat import shard_map    # noqa: E402
+from jax import shard_map                                    # noqa: E402
 
 from repro.crypto.bigint import Modulus, mont_one            # noqa: E402
 from repro.crypto import engine as engine_mod                # noqa: E402
@@ -57,9 +57,8 @@ from repro.crypto.ring import R64                            # noqa: E402
 from repro.crypto import ring                                # noqa: E402
 from repro.distributed.secure_ops import modmul_reduce       # noqa: E402
 from repro.launch import mesh as mesh_lib                    # noqa: E402
-from repro.launch.costmodel import xla_cost_analysis         # noqa: E402
 from repro.launch.dryrun import (parse_collectives,          # noqa: E402
-                                 peak_bytes, roofline_terms)
+                                 roofline_terms)
 from repro.runtime import messages as msg_lib                # noqa: E402
 
 
@@ -424,6 +423,8 @@ def tables_report(path: str, key_bits: int, engine_name: str,
 
 
 def main() -> None:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--samples", type=int, default=30720)
     ap.add_argument("--features", type=int, default=32)
@@ -543,7 +544,7 @@ def main() -> None:
     lowered = jax.jit(step, in_shardings=in_shardings).lower(*specs)
     compiled = lowered.compile()
     ma = compiled.memory_analysis()
-    ca = xla_cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text())
     # analytic roofline terms (HLO counts scan bodies once) — per-device
     # local sizes follow the chosen mesh shape
@@ -571,7 +572,7 @@ def main() -> None:
         "window": args.window, "shard_mode": args.shard_mode,
         "montmuls_per_dev": mm,
         "compile_s": round(time.time() - t0, 1),
-        "peak_bytes_per_dev": peak_bytes(ma),
+        "peak_bytes_per_dev": ma.peak_memory_in_bytes,
         "flops_per_dev": flops,
         "hbm_bytes_per_dev": float(hbm),
         "raw_hlo": {"flops": float(ca.get("flops", 0.0)),

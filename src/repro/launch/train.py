@@ -29,6 +29,8 @@ from repro.models import registry as models
 
 
 def main(argv=None) -> dict:
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="gpt-100m")
     ap.add_argument("--smoke", action="store_true")

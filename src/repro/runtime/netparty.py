@@ -674,10 +674,20 @@ class PartyServer:
                                          kind="result", payload=dump))
 
 
-def run_party_server(name: str, X, y, ready_queue,
+def run_party_server(name: str, X, y, ready_queue, platform: str,
                      host: str = "127.0.0.1",
                      checkpoint_dir: str | None = None,
                      wire: dict | None = None) -> None:
-    """Spawn entry point (multiprocessing 'spawn' target)."""
+    """Spawn entry point (multiprocessing 'spawn' target).  Pins JAX to
+    `platform` before the process's first JAX computation, so a party
+    never reaches for a chip another process holds."""
+    import sys
+
+    from repro.launch import compile_cache
+    os.environ["JAX_PLATFORMS"] = platform
+    jax.config.update("jax_platforms", platform)
+    compile_cache.enable()
+    print(f"party {name}: jax platform {platform}", file=sys.stderr,
+          flush=True)
     PartyServer(name, X, y=y, host=host,
                 checkpoint_dir=checkpoint_dir, wire=wire).run(ready_queue)

@@ -32,9 +32,8 @@ def _val_cfg(**kw):
 
 
 def _xla_flops(fn, *args) -> float:
-    from repro.launch.costmodel import xla_cost_analysis
     compiled = jax.jit(fn).lower(*args).compile()
-    return float(xla_cost_analysis(compiled)["flops"])
+    return float(compiled.cost_analysis()["flops"])
 
 
 @pytest.mark.parametrize("S,B", [(128, 2), (256, 1)])

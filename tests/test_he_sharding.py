@@ -77,9 +77,18 @@ vals = [int(v) % n for v in rng.integers(1, 1 << 60, size=7)]
 A = jnp.asarray(bigint.ints_to_limbs(vals, mod.L))
 eng1 = engine_mod.CryptoEngine(backend="jnp")
 engS = ShardedCryptoEngine(backend="jnp", mesh=mesh)
-np.testing.assert_array_equal(np.asarray(engS.mont_mul(A, A, mod)),
+got = engS.mont_mul(A, A, mod)
+np.testing.assert_array_equal(np.asarray(got),
                               np.asarray(eng1.mont_mul(A, A, mod)))
-Am = bigint.to_mont(A, mod)
+# the shards land on all four devices, not all on the first
+assert got.sharding.device_set == set(jax.devices()), got.sharding
+# jax.make_mesh gives Explicit axes; an Auto-axis mesh works the same
+meshA = jax.make_mesh((4,), ("data",),
+                      axis_types=(jax.sharding.AxisType.Auto,))
+np.testing.assert_array_equal(
+    np.asarray(ShardedCryptoEngine(backend="jnp", mesh=meshA)
+               .mont_mul(A, A, mod)), np.asarray(got))
+Am =bigint.to_mont(A, mod)
 bits = jnp.asarray(np.stack(
     [bigint.int_to_bits(int(e), 16)
      for e in rng.integers(0, 1 << 16, size=7)]))

@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles (interpret mode), with shape sweeps
-and hypothesis value sweeps."""
+and hypothesis value sweeps.  The Montgomery kernels are the RNS channel
+kernels (`ops.rns_*`); compiling them for the TPU is
+tests/test_tpu_compile.py's job."""
 import numpy as np
 import pytest
 from _hypothesis_compat import given, settings, st
@@ -33,7 +35,7 @@ def test_montmul_kernel_vs_ref(n, batch):
     b = rand_residues(n, batch)
     A = jnp.asarray(bigint.ints_to_limbs(a, mod.L))
     B = jnp.asarray(bigint.ints_to_limbs(b, mod.L))
-    got = np.asarray(ops.montmul(A, B, mod, tile_b=128))
+    got = np.asarray(ops.rns_montmul(A, B, mod, tile_b=128, interpret=True))
     want = np.asarray(ref.montmul_ref(A, B, mod))
     np.testing.assert_array_equal(got, want)
     # and against python ints
@@ -48,7 +50,7 @@ def test_montmul_kernel_batch_shapes():
     mod = Modulus.make(n)
     a = rand_residues(n, 12)
     A = jnp.asarray(bigint.ints_to_limbs(a, mod.L)).reshape(3, 4, mod.L)
-    got = ops.montmul(A, A, mod, tile_b=8)
+    got = ops.rns_montmul(A, A, mod, tile_b=8, interpret=True)
     assert got.shape == (3, 4, mod.L)
     want = ref.montmul_ref(A, A, mod)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -61,7 +63,8 @@ def test_mont_exp_bits_kernel():
     exps = rand_residues(1 << 24, 4)
     B = bigint.to_mont(jnp.asarray(bigint.ints_to_limbs(base, mod.L)), mod)
     bits = jnp.asarray(np.stack([bigint.int_to_bits(e, 24) for e in exps]))
-    got = bigint.from_mont(ops.mont_exp_bits(B, bits, mod), mod)
+    got = bigint.from_mont(
+        ops.rns_mont_exp_fused(B, bits, mod, interpret=True), mod)
     ints = [bigint.limbs_to_int(x) for x in np.asarray(got)]
     assert ints == [pow(x, e, n) for x, e in zip(base, exps)]
 
@@ -72,7 +75,7 @@ def test_ring_matmul_kernel_vs_ref(shape):
     M, K, N = shape
     a = ring.from_numpy_u64(RNG.integers(0, 1 << 64, (M, K), dtype=np.uint64))
     b = ring.from_numpy_u64(RNG.integers(0, 1 << 64, (K, N), dtype=np.uint64))
-    got = ops.ring_matmul(a, b, tm=32, tn=32)
+    got = ops.ring_matmul(a, b, tm=32, tn=32, interpret=True)
     want = ref.ring_matmul_ref(a, b)
     np.testing.assert_array_equal(np.asarray(got.hi), np.asarray(want.hi))
     np.testing.assert_array_equal(np.asarray(got.lo), np.asarray(want.lo))
@@ -93,7 +96,8 @@ def test_hypothesis_montmul_kernel(n, a, b):
     mod = Modulus.make(n)
     A = jnp.asarray(bigint.int_to_limbs(a, mod.L))[None]
     B = jnp.asarray(bigint.int_to_limbs(b, mod.L))[None]
-    got = bigint.limbs_to_int(np.asarray(ops.montmul(A, B, mod, tile_b=8))[0])
+    got = bigint.limbs_to_int(np.asarray(
+        ops.rns_montmul(A, B, mod, tile_b=8, interpret=True))[0])
     R = 1 << (12 * mod.L)
     assert got == (a * b * pow(R, -1, n)) % n
 
@@ -106,7 +110,8 @@ def test_hypothesis_montmul_kernel(n, a, b):
 def test_hypothesis_ring_matmul(avals, bvals):
     a = ring.from_numpy_u64(np.array(avals, np.uint64).reshape(2, 2))
     b = ring.from_numpy_u64(np.array(bvals, np.uint64).reshape(2, 2))
-    got = ring.to_numpy_u64(ops.ring_matmul(a, b, tm=8, tn=8))
+    got = ring.to_numpy_u64(ops.ring_matmul(a, b, tm=8, tn=8,
+                                            interpret=True))
     for i in range(2):
         for j in range(2):
             want = sum(avals[2 * i + k] * bvals[2 * k + j]

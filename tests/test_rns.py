@@ -78,6 +78,39 @@ def test_rns_montmul_compiled(n):
     np.testing.assert_array_equal(got, want)
 
 
+def test_reciprocal_remainder_is_exact():
+    """`mod_u28_recip` ≡ `%` on every channel modulus of the paper-scale
+    context, at each multiple-of-m boundary up to 2^28 and at random."""
+    ctx = rns.for_modulus(Modulus.make(MODS_SLOW[0]))
+    m = np.asarray(ctx.all_mods, np.int64)[:, None]
+    k = np.arange(0, (1 << 28) // 2**11 + 1, 97)[None, :]
+    x = np.concatenate([k * m - 1, k * m, k * m + 1,
+                        RNG.integers(0, 1 << 28, (m.shape[0], 512))], 1)
+    x = np.clip(x, 0, (1 << 28) - 1).astype(np.uint32)
+    mods = np.broadcast_to(ctx.all_mods[:, None], x.shape)
+    np.testing.assert_array_equal(
+        np.asarray(rns.mod_u28_recip(jnp.asarray(x), jnp.asarray(mods))),
+        x % mods)
+
+
+@pytest.mark.parametrize("n", [MODS[2], MODS_SLOW[0]])
+def test_library_round_on_tpu_remainder(monkeypatch, n):
+    """The library conversions and round take the reciprocal remainder
+    when JAX's backend is a TPU; the result is the same bit for bit."""
+    mod = Modulus.make(n)
+    ctx = rns.for_modulus(mod)
+    A, B = limbs(rand_residues(n, 9), mod.L), limbs(rand_residues(n, 9), mod.L)
+
+    def round_trip():
+        t = rns.rns_montmul(ctx, rns.to_rns(ctx, A), rns.to_rns_scaled(ctx, B))
+        return np.asarray(rns.from_rns(ctx, t))
+
+    want = round_trip()
+    np.testing.assert_array_equal(want, np.asarray(bigint.mont_mul(A, B, mod)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    np.testing.assert_array_equal(round_trip(), want)
+
+
 def test_rns_montmul_batch_shapes():
     n = MODS[0]
     mod = Modulus.make(n)
@@ -262,8 +295,9 @@ def test_engine_pipeline_parity(backend, pipeline, n):
 
 
 def test_engine_auto_threshold_routing():
-    """auto routes by RNS_MIN_BITS, and interpret-mode small-modulus ops
-    go to the library (never-slower-than-library guarantee)."""
+    """auto routes by RNS_MIN_BITS: small moduli to the CIOS library on
+    every backend, large ones to the RNS kernels (or the RNS library on
+    jnp) — no route of the compiled backend ends in another kernel."""
     small = Modulus.make(MODS[0])
     large = Modulus.make(MODS[2])
     jnp_eng = engine_mod.CryptoEngine(backend="jnp")
@@ -271,13 +305,15 @@ def test_engine_auto_threshold_routing():
     tpu = engine_mod.CryptoEngine(backend="pallas")
     assert jnp_eng._route(small) == "lib"
     assert jnp_eng._route(large) == "rns-jnp"
-    assert interp._route(small) == "lib"      # kernel would only add
-    assert interp._route(large) == "rns"      # interpreter overhead
-    assert tpu._route(small) == "cios"
+    assert interp._route(small) == "lib"
+    assert interp._route(large) == "rns"
+    assert tpu._route(small) == "lib"
     assert tpu._route(large) == "rns"
     # explicit pipelines pin the arithmetic
-    assert engine_mod.CryptoEngine(backend="pallas-interpret",
-                                   pipeline="cios")._route(large) == "cios"
+    assert engine_mod.CryptoEngine(backend="pallas",
+                                   pipeline="cios")._route(large) == "lib"
+    assert engine_mod.CryptoEngine(backend="pallas",
+                                   pipeline="rns")._route(small) == "rns"
     assert engine_mod.CryptoEngine(backend="jnp",
                                    pipeline="rns")._route(small) == "rns-jnp"
 
