@@ -27,6 +27,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.crypto import ring
 from repro.crypto.ring import R64
 from repro.mpc import beaver, pairwise
@@ -54,9 +55,12 @@ class LegCtx:
     f: int
 
 
-def _pair(leg_fn: Callable[[PairLeg, LegCtx], R64]
+def _pair(leg_fn: Callable[[PairLeg, LegCtx], R64], what: str
           ) -> Callable[[ShareCtx], tuple[R64, R64]]:
-    """Lift a per-CP leg to the pair-at-once simulation API."""
+    """Lift a per-CP leg to the pair-at-once simulation API, run inside
+    the span ``vfl.protocol.<what>``."""
+    name = "vfl.protocol." + what
+
     def pair_fn(ctx: ShareCtx) -> tuple[R64, R64]:
         def run(leg: PairLeg) -> R64:
             i = leg.index
@@ -65,7 +69,8 @@ def _pair(leg_fn: Callable[[PairLeg, LegCtx], R64]
                 y=None if ctx.y is None else ctx.y[i],
                 ez=None if ctx.ez is None else ctx.ez[i],
                 f=ctx.f))
-        return pairwise.joint(run, ctx.dealer)
+        with obs.span(name):
+            return pairwise.joint(run, ctx.dealer)
     return pair_fn
 
 
@@ -83,9 +88,11 @@ def ez_chain_leg(leg: PairLeg, ez_list: list[R64], f: int) -> R64:
 def ez_chain_pair(ez_shares: list[tuple[R64, R64]], f: int, dealer
                   ) -> tuple[R64, R64]:
     """Pair-at-once form of `ez_chain_leg` (simulation scheduler)."""
-    return pairwise.joint(
-        lambda leg: ez_chain_leg(leg, [s[leg.index] for s in ez_shares], f),
-        dealer)
+    with obs.span("vfl.protocol.ez_chain"):
+        return pairwise.joint(
+            lambda leg: ez_chain_leg(leg, [s[leg.index] for s in ez_shares],
+                                     f),
+            dealer)
 
 
 # ---------------------------------------------------------------------------
@@ -111,8 +118,8 @@ def lr_loss_leg(leg: PairLeg, c: LegCtx) -> R64:
     return leg.add_pub(s, ln2)
 
 
-lr_gradient_operator = _pair(lr_gradient_leg)
-lr_loss_shares = _pair(lr_loss_leg)
+lr_gradient_operator = _pair(lr_gradient_leg, "grad_op")
+lr_loss_shares = _pair(lr_loss_leg, "loss")
 
 
 # ---------------------------------------------------------------------------
@@ -133,8 +140,8 @@ def pr_loss_leg(leg: PairLeg, c: LegCtx) -> R64:
     return ring.sum_axis(ring.sub(t, c.ez), 0)
 
 
-pr_gradient_operator = _pair(pr_gradient_leg)
-pr_loss_shares = _pair(pr_loss_leg)
+pr_gradient_operator = _pair(pr_gradient_leg, "grad_op")
+pr_loss_shares = _pair(pr_loss_leg, "loss")
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +237,8 @@ POISSON = GLM(
 
 LINEAR = GLM(   # bonus GLM (paper: "also suitable for Linear, Gamma, …")
     name="linear",
-    gradient_operator=_pair(linear_gradient_leg),
-    loss_shares=_pair(linear_loss_leg),
+    gradient_operator=_pair(linear_gradient_leg, "grad_op"),
+    loss_shares=_pair(linear_loss_leg, "loss"),
     needs_exp=False,
     d_float=lambda wx, y: wx - y,
     loss_float=lambda wx, y: float(0.5 * np.mean((wx - y) ** 2)),
@@ -269,8 +276,8 @@ def gamma_loss_leg(leg: PairLeg, c: LegCtx) -> R64:
     return ring.sum_axis(ring.add(c.z, prod), 0)
 
 
-gamma_gradient_operator = _pair(gamma_gradient_leg)
-gamma_loss_shares = _pair(gamma_loss_leg)
+gamma_gradient_operator = _pair(gamma_gradient_leg, "grad_op")
+gamma_loss_shares = _pair(gamma_loss_leg, "loss")
 
 GAMMA = GLM(
     name="gamma",

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.crypto import bigint, fixed_point, paillier, prng, ring
 from repro.crypto import engine as engine_mod
 from repro.crypto.bigint import mont_mul, mont_one
@@ -173,7 +174,7 @@ def he_matvec(pub: paillier.PublicKey, cts: jnp.ndarray,
     if engine_routed:
         return eng.he_matvec_windowed(cts, digits, pub.mod_n2, window)
     return _he_matvec_windowed(_HashablePub(pub), cts,
-                               jnp.asarray(digits, _U32), window)
+                               obs.to_device(digits, _U32), window)
 
 
 # ---------------------------------------------------------------------------
@@ -252,13 +253,15 @@ class PaillierBackend:
         r^n ladder otherwise."""
         pub = self.keys[party].pub
         table = self.tables.get(party)
-        if table is not None:
-            from repro.crypto import fixed_base
-            digits = fixed_base.draw_exponent_digits(table, count, self.rng)
-            return (paillier.noise_from_table, pub, table, digits,
-                    self.engine)
-        raw = paillier.raw_noise(pub, count, self.rng)
-        return (paillier.noise_to_mont, pub, raw, self.engine)
+        with obs.span("vfl.crypto.noise_draw", key_owner=party, rows=count):
+            if table is not None:
+                from repro.crypto import fixed_base
+                digits = fixed_base.draw_exponent_digits(table, count,
+                                                         self.rng)
+                return (paillier.noise_from_table, pub, table, digits,
+                        self.engine)
+            raw = paillier.raw_noise(pub, count, self.rng)
+            return (paillier.noise_to_mont, pub, raw, self.engine)
 
     def prefetch_noise(self, party: str, count: int) -> None:
         """Schedule `count` fresh encryption noises under `party`'s key."""
@@ -293,29 +296,39 @@ class PaillierBackend:
         rn = self._pooled_noise(party, count)
         if rn is None:                          # pool miss: compute inline
             fn, *args = self._noise_job(party, count)
-            rn = fn(*args)
-        m = jnp.asarray(m_limbs, _U32)
-        rn = jnp.asarray(rn, _U32).reshape(m.shape[:-1] + (pub.Ln2,))
+            with obs.span("vfl.crypto.noise_exp", key_owner=party,
+                          rows=count):
+                rn = fn(*args)
+        m = obs.to_device(m_limbs, _U32)
+        rn = obs.to_device(rn, _U32).reshape(m.shape[:-1] + (pub.Ln2,))
         return paillier.encrypt_with_noise(pub, m, rn, self.engine)
 
     # -- protocol ops -------------------------------------------------------
     def encrypt_share(self, party: str, d: R64) -> jnp.ndarray:
-        pub = self.keys[party].pub
-        m = fixed_point.r64_to_limbs(d, pub.Ln)
-        count = int(np.prod(m.shape[:-1])) if m.ndim > 1 else 1
-        return self._encrypt(pub, m, party, count)
+        with obs.span("vfl.crypto.encrypt", key_owner=party,
+                      rows=d.lo.shape[0] if d.lo.ndim else 1):
+            pub = self.keys[party].pub
+            m = fixed_point.r64_to_limbs(d, pub.Ln)
+            count = int(np.prod(m.shape[:-1])) if m.ndim > 1 else 1
+            return self._encrypt(pub, m, party, count)
 
     def matvec(self, party: str, cts, exps, width, digits=None
                ) -> jnp.ndarray:
-        return he_matvec(self.keys[party].pub, cts, exps, width,
-                         digits=digits, engine=self.engine)
+        rows, cols = exps.shape
+        with obs.span("vfl.crypto.matvec", key_owner=party, rows=rows,
+                      cols=cols):
+            return he_matvec(self.keys[party].pub, cts, exps, width,
+                             digits=digits, engine=self.engine)
 
     def add_mask(self, party: str, cts, mask_ints: list[int]) -> jnp.ndarray:
         """cts ⊕ Enc(R) with fresh noise — masks AND re-randomizes."""
-        pub = self.keys[party].pub
-        m = bigint.ints_to_limbs(mask_ints, pub.Ln)
-        cr = self._encrypt(pub, m, party, len(mask_ints))
-        return paillier.add_ct(pub, cts, cr, self.engine)
+        with obs.span("vfl.crypto.add_mask", key_owner=party,
+                      rows=len(mask_ints)):
+            pub = self.keys[party].pub
+            with obs.span("vfl.crypto.to_limbs", rows=len(mask_ints)):
+                m = bigint.ints_to_limbs(mask_ints, pub.Ln)
+            cr = self._encrypt(pub, m, party, len(mask_ints))
+            return paillier.add_ct(pub, cts, cr, self.engine)
 
     def decrypt_to_r64(self, party: str, cts) -> R64:
         key = self.keys[party]
@@ -323,8 +336,10 @@ class PaillierBackend:
             raise PermissionError(
                 f"cannot decrypt under {party!r}: this backend view holds "
                 "only the peer's public key (distributed runtime)")
-        dec = paillier.decrypt_crt(key, cts, engine=self.engine)
-        return fixed_point.limbs_to_r64(dec)
+        with obs.span("vfl.crypto.decrypt", key_owner=party,
+                      rows=cts.shape[0] if cts.ndim > 1 else 1):
+            dec = paillier.decrypt_crt(key, cts, engine=self.engine)
+            return fixed_point.limbs_to_r64(dec)
 
 
 class MockHEBackend:
@@ -419,7 +434,7 @@ def mask_to_r64(R: list[int]) -> R64:
 
 def local_grad_share(feats: EncodedFeatures, d_self: R64) -> R64:
     """Protocol 3 line 2 — a CP's local term X_p^T ⟨d⟩_p."""
-    return _from_col(ring.matmul(jnp.asarray(feats.x_int.T),
+    return _from_col(ring.matmul(obs.to_device(feats.x_int.T),
                                  _as_col(d_self)))
 
 
@@ -430,7 +445,7 @@ def masked_matvec(backend, key_owner: str, d_ct, feats: EncodedFeatures,
     re-randomized.  Returns (enc_masked, R_mod264) — the caller ships
     enc_masked as a `P3.masked_grad` message and keeps R for unmasking."""
     m = feats.exps.shape[1]
-    enc_g = backend.matvec(key_owner, d_ct, jnp.asarray(feats.exps),
+    enc_g = backend.matvec(key_owner, d_ct, obs.to_device(feats.exps),
                            feats.width, digits=feats.digits)
     R = mask_ints(mask_bound_bits, m, rng)
     return backend.add_mask(key_owner, enc_g, R), mask_to_r64(R)

@@ -54,6 +54,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.crypto import bigint
 from repro.crypto.bigint import Modulus
 
@@ -211,6 +212,11 @@ class CryptoEngine:
         Returns:
           (..., L) canonical Montgomery-domain product.
         """
+        obs.count_engine_call("mont_mul")
+        return self._mont_mul(a, b, mod)
+
+    def _mont_mul(self, a: jnp.ndarray, b: jnp.ndarray,
+                  mod: Modulus) -> jnp.ndarray:
         if self.sharded:
             from repro.distributed import he_sharding
             return he_sharding.sharded_mont_mul(self, a, b, mod)
@@ -238,6 +244,7 @@ class CryptoEngine:
           (..., L) Montgomery-domain base^e, canonical.  The kernel path
           runs the whole ladder in ONE pallas_call.
         """
+        obs.count_engine_call("mont_exp_bits")
         if self.sharded:
             from repro.distributed import he_sharding
             return he_sharding.sharded_mont_exp_bits(self, base, bits, mod)
@@ -258,7 +265,7 @@ class CryptoEngine:
         `mont_exp_bits`; e == 0 short-circuits to mont(1)."""
         if e == 0:
             return jnp.broadcast_to(bigint.mont_one(mod), base.shape)
-        bits = jnp.asarray(bigint.cached_bits(int(e), int(e).bit_length()))
+        bits = obs.to_device(bigint.cached_bits(int(e), int(e).bit_length()))
         return self.mont_exp_bits(base, bits, mod)
 
     def he_matvec_windowed(self, cts: jnp.ndarray, digits,
@@ -280,11 +287,12 @@ class CryptoEngine:
         body of a sharded jnp engine; single-device callers
         (`protocols.he_matvec`) run their own jitted copy of it.
         """
+        obs.count_engine_call("he_matvec_windowed")
         if self.sharded:
             from repro.distributed import he_sharding
             return he_sharding.sharded_he_matvec(self, cts, digits, mod,
                                                  window)
-        digits = jnp.asarray(digits, _U32)
+        digits = obs.to_device(digits, _U32)
         route = self._route(mod)
         if route == "lib":
             return _lib_he_matvec(cts, digits, mod, window)
@@ -317,8 +325,9 @@ class CryptoEngine:
         (runtime noise pool), so a sharded engine evaluates on its own
         device.
         """
-        digits = jnp.asarray(digits, _U32)
-        table_rns = jnp.asarray(table.table_rns, _U32)
+        obs.count_engine_call("fixed_base_exp")
+        digits = obs.to_device(digits, _U32)
+        table_rns = obs.to_device(table.table_rns, _U32)
         if self.uses_kernels:
             from repro.kernels import ops
             return ops.rns_fixed_base_fused(table_rns, digits, mod,
@@ -331,12 +340,14 @@ class CryptoEngine:
     # -- derived conveniences (same dispatch, used by paillier.py) ----------
     def to_mont(self, a: jnp.ndarray, mod: Modulus) -> jnp.ndarray:
         """Lift canonical residues into the Montgomery domain (⊙ R² )."""
-        return self.mont_mul(a, jnp.asarray(mod.r2, _U32), mod)
+        obs.count_engine_call("to_mont")
+        return self._mont_mul(a, obs.to_device(mod.r2, _U32), mod)
 
     def from_mont(self, a: jnp.ndarray, mod: Modulus) -> jnp.ndarray:
         """Drop Montgomery-domain residues back to plain canonical form."""
+        obs.count_engine_call("from_mont")
         one = jnp.zeros(mod.L, _U32).at[0].set(1)
-        return self.mont_mul(a, one, mod)
+        return self._mont_mul(a, one, mod)
 
 
 def _lib_he_matvec(cts, digits, mod: Modulus, window: int) -> jnp.ndarray:

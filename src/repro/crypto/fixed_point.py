@@ -13,6 +13,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.crypto import bigint, ring
 from repro.crypto.ring import R64
 
@@ -41,28 +42,32 @@ def encode_pub_int(x, f: int = DEFAULT_FRAC_BITS) -> np.ndarray:
 
 def r64_to_limbs(a: R64, L: int) -> jnp.ndarray:
     """Ring residue (unsigned 64-bit value) -> L-limb vector (L >= 6)."""
-    shifts_lo = [0, 12, 24]           # limbs 0..2 from lo (+ bridge)
-    limbs = []
-    lo, hi = a.lo, a.hi
-    limbs.append(lo & _U32(0xFFF))                                  # bits 0-11
-    limbs.append((lo >> 12) & _U32(0xFFF))                          # 12-23
-    limbs.append((lo >> 24) | ((hi & _U32(0xF)) << 8))              # 24-35
-    limbs.append((hi >> 4) & _U32(0xFFF))                           # 36-47
-    limbs.append((hi >> 16) & _U32(0xFFF))                          # 48-59
-    limbs.append(hi >> 28)                                          # 60-63
-    del shifts_lo
-    out = jnp.stack(limbs, axis=-1) & _U32(0xFFF)
-    pad = jnp.zeros(out.shape[:-1] + (L - _R64_LIMBS,), _U32)
-    return jnp.concatenate([out, pad], axis=-1)
+    with obs.span("vfl.crypto.to_limbs",
+                  rows=a.lo.shape[0] if a.lo.ndim else 1):
+        shifts_lo = [0, 12, 24]           # limbs 0..2 from lo (+ bridge)
+        limbs = []
+        lo, hi = a.lo, a.hi
+        limbs.append(lo & _U32(0xFFF))                              # bits 0-11
+        limbs.append((lo >> 12) & _U32(0xFFF))                      # 12-23
+        limbs.append((lo >> 24) | ((hi & _U32(0xF)) << 8))          # 24-35
+        limbs.append((hi >> 4) & _U32(0xFFF))                       # 36-47
+        limbs.append((hi >> 16) & _U32(0xFFF))                      # 48-59
+        limbs.append(hi >> 28)                                      # 60-63
+        del shifts_lo
+        out = jnp.stack(limbs, axis=-1) & _U32(0xFFF)
+        pad = jnp.zeros(out.shape[:-1] + (L - _R64_LIMBS,), _U32)
+        return jnp.concatenate([out, pad], axis=-1)
 
 
 def limbs_to_r64(x: jnp.ndarray) -> R64:
     """Low 64 bits of a limb vector -> ring residue (i.e. reduce mod 2^64)."""
-    x = x.astype(_U32)
-    l0, l1, l2, l3, l4, l5 = (x[..., i] for i in range(6))
-    lo = l0 | (l1 << 12) | (l2 << 24)
-    hi = (l2 >> 8) | (l3 << 4) | (l4 << 16) | (l5 << 28)
-    return R64(hi, lo)
+    with obs.span("vfl.crypto.from_limbs",
+                  rows=x.shape[0] if x.ndim > 1 else 1):
+        x = x.astype(_U32)
+        l0, l1, l2, l3, l4, l5 = (x[..., i] for i in range(6))
+        lo = l0 | (l1 << 12) | (l2 << 24)
+        hi = (l2 >> 8) | (l3 << 4) | (l4 << 16) | (l5 << 28)
+        return R64(hi, lo)
 
 
 def u64_bits_msb(a: R64, nbits: int = 64) -> jnp.ndarray:

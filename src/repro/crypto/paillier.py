@@ -25,6 +25,7 @@ from typing import Any
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.crypto import bigint
 from repro.crypto import engine as engine_mod
 from repro.crypto.bigint import (LIMB_BITS, Modulus, add_small, big_mul_full,
@@ -269,7 +270,7 @@ def noise_to_mont(pub: PublicKey, r_limbs, engine=None) -> jnp.ndarray:
     runtime's noise pool runs exactly this on the scheduler's thread
     pool, overlapped with the Protocol-3 legs)."""
     eng = _eng(engine)
-    rm = eng.to_mont(jnp.asarray(r_limbs, _U32), pub.mod_n2)
+    rm = eng.to_mont(obs.to_device(r_limbs, _U32), pub.mod_n2)
     return eng.mont_exp_const(rm, pub.n, pub.mod_n2)
 
 
@@ -301,11 +302,11 @@ def encrypt_with_noise(pub: PublicKey, m_limbs, rn_mont,
     With pooled noise, encryption off the critical path costs ~one
     mont_mul."""
     eng = _eng(engine)
-    m = jnp.asarray(m_limbs, _U32)
-    mn = big_mul_full(m, jnp.asarray(pub.n_limbs, _U32), pub.Ln2)
+    m = obs.to_device(m_limbs, _U32)
+    mn = big_mul_full(m, obs.to_device(pub.n_limbs, _U32), pub.Ln2)
     c0 = add_small(mn, 1)
     return eng.mont_mul(eng.to_mont(c0, pub.mod_n2),
-                        jnp.asarray(rn_mont, _U32), pub.mod_n2)
+                        obs.to_device(rn_mont, _U32), pub.mod_n2)
 
 
 def encrypt(pub: PublicKey, m_limbs, rng: np.random.Generator | None = None,
@@ -331,13 +332,13 @@ def decrypt(priv: PrivateKey, c_mont, engine=None) -> jnp.ndarray:
 
 def _dec_component(comp: CRTComponent, c_modp2_mont, eng) -> jnp.ndarray:
     """m_p = L_p(c^{p-1} mod p²) · h_p mod p."""
-    u_m = eng.mont_exp_bits(c_modp2_mont, jnp.asarray(comp.lam_bits),
+    u_m = eng.mont_exp_bits(c_modp2_mont, obs.to_device(comp.lam_bits),
                             comp.mod_p2)
     u = eng.from_mont(u_m, comp.mod_p2)
     um1 = sub_small(u, 1)
-    k = mul_low(um1, jnp.asarray(comp.hensel_p, _U32),
+    k = mul_low(um1, obs.to_device(comp.hensel_p, _U32),
                 comp.mod_p2.L)[..., :comp.mod_p.L]
-    return eng.mont_mul(k, jnp.asarray(comp.h_mont, _U32), comp.mod_p)
+    return eng.mont_mul(k, obs.to_device(comp.h_mont, _U32), comp.mod_p)
 
 
 def decrypt_crt(priv: PrivateKey, c_mont, engine=None) -> jnp.ndarray:
@@ -348,7 +349,7 @@ def decrypt_crt(priv: PrivateKey, c_mont, engine=None) -> jnp.ndarray:
     eng = _eng(engine)
     pub = priv.pub
     cp, cq = priv.crt_p, priv.crt_q
-    c = jnp.asarray(c_mont, _U32)
+    c = obs.to_device(c_mont, _U32)
     # ciphertext is Montgomery mod n²: leave the domain, then reduce
     c_plain = eng.from_mont(c, pub.mod_n2)
     cp2 = eng.to_mont(_reduce_mod(c_plain, cp.mod_p2, eng), cp.mod_p2)
@@ -361,9 +362,9 @@ def decrypt_crt(priv: PrivateKey, c_mont, engine=None) -> jnp.ndarray:
                        + [(0, max(0, Lq - m_p.shape[-1]))])[..., :Lq]
     from repro.crypto.bigint import mod_sub
     t = mod_sub(m_q, _reduce_mod(m_p_padq, cq.mod_p, eng), cq.mod_p)
-    u = eng.mont_mul(t, jnp.asarray(priv.q_pinv_mont, _U32), cq.mod_p)
-    pu = big_mul_full(jnp.asarray(int_to_limbs(cp.prime, cp.mod_p.L), _U32),
-                      u, pub.Ln)
+    u = eng.mont_mul(t, obs.to_device(priv.q_pinv_mont, _U32), cq.mod_p)
+    pu = big_mul_full(obs.to_device(int_to_limbs(cp.prime, cp.mod_p.L),
+                                    _U32), u, pub.Ln)
     m_p_padn = jnp.pad(m_p, [(0, 0)] * (m_p.ndim - 1)
                        + [(0, pub.Ln - m_p.shape[-1])])
     from repro.crypto.bigint import _add_limbs

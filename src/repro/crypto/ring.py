@@ -14,6 +14,8 @@ from typing import NamedTuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 _U32 = jnp.uint32
 
 
@@ -36,8 +38,8 @@ def zeros(shape) -> R64:
 
 def from_numpy_u64(x: np.ndarray) -> R64:
     x = np.asarray(x, np.uint64)
-    return R64(jnp.asarray((x >> np.uint64(32)).astype(np.uint32)),
-               jnp.asarray((x & np.uint64(0xFFFFFFFF)).astype(np.uint32)))
+    return R64(obs.to_device((x >> np.uint64(32)).astype(np.uint32)),
+               obs.to_device((x & np.uint64(0xFFFFFFFF)).astype(np.uint32)))
 
 
 def to_numpy_u64(x: R64) -> np.ndarray:

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.crypto import paillier, prng, ring
 from repro.crypto import bigint, fixed_point
 from repro.crypto.ring import R64
@@ -57,7 +58,8 @@ class DealerTripleSource:
         return sub
 
     def state(self) -> dict:
-        return {"key": np.asarray(jax.random.key_data(self._key)),
+        return {"key": obs.to_host(jax.random.key_data(self._key),
+                                   "dealer", "protocol"),
                 "drawn": int(self.drawn)}
 
     def set_state(self, st: dict) -> None:

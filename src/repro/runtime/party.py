@@ -41,6 +41,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import glm as glm_lib
 from repro.core import protocols
 from repro.crypto import fixed_point, ring
@@ -90,16 +91,19 @@ class CPRole:
         """Protocol 3 line 1: encrypt ⟨d⟩ under own key, send to the peer
         (the broadcast to non-CPs reuses the same ciphertext)."""
         st = self.cp
-        st.ct_self = self.backend.encrypt_share(self.name, st.d_self)
-        # line 2 (local): own term X_p^T ⟨d⟩_p joins the gradient sum.
-        # Accumulate (don't assign): under WAN latency the peer CP can
-        # race ahead — for GLMs whose gradient needs no Beaver openings
-        # (logistic) its EncD round-trip can complete while this party is
-        # still collecting Protocol-1 shares, in which case the peer's
-        # unmasked term is already sitting in `_grad_acc`.
-        local = protocols.local_grad_share(self._feats_b, st.d_self)
-        self._grad_acc = local if self._grad_acc is None \
-            else ring.add(self._grad_acc, local)
+        with obs.span("vfl.protocol.enc_d", party=self.name,
+                      key_owner=self.name):
+            st.ct_self = self.backend.encrypt_share(self.name, st.d_self)
+            # line 2 (local): own term X_p^T ⟨d⟩_p joins the gradient sum.
+            # Accumulate (don't assign): under WAN latency the peer CP can
+            # race ahead — for GLMs whose gradient needs no Beaver
+            # openings (logistic) its EncD round-trip can complete while
+            # this party is still collecting Protocol-1 shares, in which
+            # case the peer's unmasked term is already sitting in
+            # `_grad_acc`.
+            local = protocols.local_grad_share(self._feats_b, st.d_self)
+            self._grad_acc = local if self._grad_acc is None \
+                else ring.add(self._grad_acc, local)
         self._grad_ready = True
         if not self._pending_unmask:
             self._apply_update()
@@ -117,9 +121,11 @@ class CPRole:
     def _decrypt_unmask(self, m: msg.MaskedGrad) -> list[msg.Message]:
         """Protocol 3 line 7 (key owner): decrypt + offset-correct with the
         own d-share, return the ring share to the requester."""
-        w = protocols.decrypt_offset_corrected(
-            self.backend, self.name, m.payload, self.cp.d_self,
-            self._feats_b.width)
+        with obs.span("vfl.protocol.decrypt_unmask", party=m.src,
+                      key_owner=self.name):
+            w = protocols.decrypt_offset_corrected(
+                self.backend, self.name, m.payload, self.cp.d_self,
+                self._feats_b.width)
         return [msg.UnmaskedShare(self.name, m.src, w)]
 
 
@@ -209,8 +215,9 @@ class Party(CPRole):
         Returns:
           Two `P1.z_share` messages (R64, f fractional bits), one per CP.
         """
-        val = fixed_point.encode(self._wx, self.cfg.f)
-        s0, s1 = sharing.share(val, key)
+        with obs.span("vfl.protocol.share_z", party=self.name):
+            val = fixed_point.encode(self._wx, self.cfg.f)
+            s0, s1 = sharing.share(val, key)
         return [msg.ZShare(self.name, self._cps[0], s0),
                 msg.ZShare(self.name, self._cps[1], s1)]
 
@@ -219,8 +226,9 @@ class Party(CPRole):
         (exp_sign = GLM.exp_sign: +1 Poisson, −1 Gamma; input clipped to
         [−30, 8] before exp).  Returns two `P1.ez_share` messages (R64,
         f fractional bits)."""
-        ezp = np.exp(np.clip(exp_sign * self._wx, -30, 8))
-        s0, s1 = sharing.share(fixed_point.encode(ezp, self.cfg.f), key)
+        with obs.span("vfl.protocol.share_ez", party=self.name):
+            ezp = np.exp(np.clip(exp_sign * self._wx, -30, 8))
+            s0, s1 = sharing.share(fixed_point.encode(ezp, self.cfg.f), key)
         return [msg.EzShare(self.name, self._cps[0], s0),
                 msg.EzShare(self.name, self._cps[1], s1)]
 
@@ -253,9 +261,11 @@ class Party(CPRole):
     def _produce_masked_grad(self, m: msg.Message) -> list[msg.Message]:
         """Feature owner's leg: matvec under the d-owner's key + mask."""
         owner = m.key_owner
-        enc_masked, Rr = protocols.masked_matvec(
-            self.backend, owner, m.payload, self._feats_b,
-            self._mask_bound, self.rng)
+        with obs.span("vfl.protocol.masked_grad", party=self.name,
+                      key_owner=owner):
+            enc_masked, Rr = protocols.masked_matvec(
+                self.backend, owner, m.payload, self._feats_b,
+                self._mask_bound, self.rng)
         self._masks[owner] = Rr
         return [msg.MaskedGrad(self.name, owner, enc_masked,
                                n_cts=self.X.shape[1],
@@ -274,9 +284,10 @@ class Party(CPRole):
     def _apply_update(self) -> None:
         """Eq. 6 — local: decode the (fx+f)-fractional-bit gradient, scale
         by 1/m, step.  Weights never leave the party."""
-        g = fixed_point.decode(self._grad_acc, self.cfg.fx + self.cfg.f) \
-            / self._nb
-        self.W = self.W - self.cfg.lr * g
+        with obs.span("vfl.protocol.update", party=self.name):
+            acc = obs.to_host(self._grad_acc, "grad", "protocol")
+            g = fixed_point.decode(acc, self.cfg.fx + self.cfg.f) / self._nb
+            self.W = self.W - self.cfg.lr * g
 
     # -- Protocol 4 ---------------------------------------------------------
     def _absorb_loss(self, m: msg.LossShare) -> list[msg.Message]:
@@ -356,8 +367,9 @@ class LabelParty(Party):
         self._wx_by_src: dict[str, np.ndarray] = {}
 
     def share_y(self, key) -> list[msg.Message]:
-        val = fixed_point.encode(self.y[self._idx], self.cfg.f)
-        s0, s1 = sharing.share(val, key)
+        with obs.span("vfl.protocol.share_y", party=self.name):
+            val = fixed_point.encode(self.y[self._idx], self.cfg.f)
+            s0, s1 = sharing.share(val, key)
         return [msg.YShare(self.name, self._cps[0], s0),
                 msg.YShare(self.name, self._cps[1], s1)]
 
@@ -367,6 +379,7 @@ class LabelParty(Party):
             total = sharing.reconstruct(self.cp.l_self, m.payload)
         else:
             total = m.payload               # forwarded (reconstructed) by CP0
+        total = obs.to_host(total, "loss", "protocol")
         revealed = float(fixed_point.decode(total, self.cfg.f))
         self.losses.append(self.model.finalize_loss(
             revealed, self.y[self._idx], self._nb))

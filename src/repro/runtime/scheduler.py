@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import glm as glm_lib
 from repro.core import protocols
 from repro.mpc import beaver
@@ -222,17 +223,19 @@ class VFLScheduler:
     def _iteration(self, idx) -> None:
         cfg, tp = self.cfg, self.transport
         nb = len(idx)
-        cps = self._select_cps()
-        noncps = [p.name for p in self.parties if p.name not in cps]
-        self.jkey, *subkeys = jax.random.split(
-            self.jkey, len(self.names) * 2 + 3)
-        for p in self.parties:
-            p.begin_iteration(idx, cps, nb, self.mask_bound)
-        cp0, cp1 = self.by_name[cps[0]], self.by_name[cps[1]]
-        ex = tp.executor
-        concurrent = ex is not None and getattr(tp, "concurrent_legs", False)
-        if tp.overlaps_p3:
-            self._prefetch_noise(cps, nb)
+        with obs.span("vfl.conductor.begin", rows=nb):
+            cps = self._select_cps()
+            noncps = [p.name for p in self.parties if p.name not in cps]
+            self.jkey, *subkeys = jax.random.split(
+                self.jkey, len(self.names) * 2 + 3)
+            for p in self.parties:
+                p.begin_iteration(idx, cps, nb, self.mask_bound)
+            cp0, cp1 = self.by_name[cps[0]], self.by_name[cps[1]]
+            ex = tp.executor
+            concurrent = ex is not None and getattr(tp, "concurrent_legs",
+                                                    False)
+            if tp.overlaps_p3:
+                self._prefetch_noise(cps, nb)
 
         # -- Protocol 1: share intermediate results -------------------------
         # Each party's share computation (local matvec + encode + split) is
@@ -240,77 +243,85 @@ class VFLScheduler:
         # allows; results are POSTED in party order either way, keeping
         # delivery — and hence the CPs' order-sensitive ez chaining —
         # deterministic.
-        for out in self._fanout(
-                [functools.partial(p.share_z, subkeys[i])
-                 for i, p in enumerate(self.parties)]
-                + [functools.partial(self.label_party.share_y,
-                                     subkeys[len(self.names)])]):
-            tp.post_all(out)
-        tp.pump(order=list(cps))
-        mdealer = TransportDealer(self.dealer, tp, cps[0], cps[1])
-        ez = None
-        if self.model.needs_exp:
+        with obs.span("vfl.conductor.p1"):
             for out in self._fanout(
-                    [functools.partial(p.share_ez,
-                                       subkeys[len(self.names) + 1 + i],
-                                       self.model.exp_sign)
-                     for i, p in enumerate(self.parties)]):
+                    [functools.partial(p.share_z, subkeys[i])
+                     for i, p in enumerate(self.parties)]
+                    + [functools.partial(self.label_party.share_y,
+                                         subkeys[len(self.names)])]):
                 tp.post_all(out)
             tp.pump(order=list(cps))
-            # e^{Σz_p} = Π e^{z_p}: chained Beaver products over the pair
-            # (roster order — arrival order is racy under pump_async)
-            e0 = cp0.cp.ez_ordered(self.names)
-            e1 = cp1.cp.ez_ordered(self.names)
-            ez = glm_lib.ez_chain_pair(list(zip(e0, e1)), cfg.f, mdealer)
+            mdealer = TransportDealer(self.dealer, tp, cps[0], cps[1])
+            ez = None
+            if self.model.needs_exp:
+                for out in self._fanout(
+                        [functools.partial(p.share_ez,
+                                           subkeys[len(self.names) + 1 + i],
+                                           self.model.exp_sign)
+                         for i, p in enumerate(self.parties)]):
+                    tp.post_all(out)
+                tp.pump(order=list(cps))
+                # e^{Σz_p} = Π e^{z_p}: chained Beaver products over the
+                # pair (roster order — arrival order is racy under
+                # pump_async)
+                e0 = cp0.cp.ez_ordered(self.names)
+                e1 = cp1.cp.ez_ordered(self.names)
+                ez = glm_lib.ez_chain_pair(list(zip(e0, e1)), cfg.f, mdealer)
 
         ctx = glm_lib.ShareCtx(z=(cp0.cp.z_acc, cp1.cp.z_acc),
                                y=(cp0.cp.y_share, cp1.cp.y_share),
                                ez=ez, f=cfg.f, dealer=mdealer)
 
         # -- Protocol 2: gradient-operator on shares ------------------------
-        d0, d1 = self.model.gradient_operator(ctx)
-        cp0.cp.d_self, cp1.cp.d_self = d0, d1
+        with obs.span("vfl.conductor.p2"):
+            d0, d1 = self.model.gradient_operator(ctx)
+            cp0.cp.d_self, cp1.cp.d_self = d0, d1
 
         # -- Protocol 3: secure gradients -----------------------------------
         # The two CPs' encrypt legs fan out on the pool when possible.
-        enc0, enc1 = self._fanout([cp0.announce_enc_d, cp1.announce_enc_d])
-        tp.post(enc0)
-        tp.post(enc1)
-        if concurrent:
-            # Concurrent legs: every masked-matvec / decrypt / unmask
-            # leg of all k parties becomes an independent pool future
-            # (pump_async) — the k−2 non-CP legs overlap instead of
-            # queueing.  pump_async's return is the join barrier before
-            # Protocol 4; the ring accumulations it races commute
-            # exactly, so the trained model is bit-identical to the
-            # sequential schedule (tests/test_runtime_parity.py, k=8).
-            for cp in (cp0, cp1):
-                tp.post_all(cp.broadcast_enc_d(noncps))
-            tp.pump_async(order=[*cps, *noncps])
-        elif tp.overlaps_p3:
-            # broadcasts are data-independent of the CP exchange:
-            # same sweep
-            for cp in (cp0, cp1):
-                tp.post_all(cp.broadcast_enc_d(noncps))
-            tp.pump(order=[*cps, *noncps])
-        else:
-            tp.pump(order=list(cps))
-            for p in noncps:
+        with obs.span("vfl.conductor.p3"):
+            enc0, enc1 = self._fanout([cp0.announce_enc_d,
+                                       cp1.announce_enc_d])
+            tp.post(enc0)
+            tp.post(enc1)
+            if concurrent:
+                # Concurrent legs: every masked-matvec / decrypt / unmask
+                # leg of all k parties becomes an independent pool future
+                # (pump_async) — the k−2 non-CP legs overlap instead of
+                # queueing.  pump_async's return is the join barrier
+                # before Protocol 4; the ring accumulations it races
+                # commute exactly, so the trained model is bit-identical
+                # to the sequential schedule (tests/test_runtime_parity.py,
+                # k=8).
                 for cp in (cp0, cp1):
-                    tp.post_all(cp.broadcast_enc_d([p]))
-            tp.pump(order=[*noncps, *cps])
+                    tp.post_all(cp.broadcast_enc_d(noncps))
+                tp.pump_async(order=[*cps, *noncps])
+            elif tp.overlaps_p3:
+                # broadcasts are data-independent of the CP exchange:
+                # same sweep
+                for cp in (cp0, cp1):
+                    tp.post_all(cp.broadcast_enc_d(noncps))
+                tp.pump(order=[*cps, *noncps])
+            else:
+                tp.pump(order=list(cps))
+                for p in noncps:
+                    for cp in (cp0, cp1):
+                        tp.post_all(cp.broadcast_enc_d([p]))
+                tp.pump(order=[*noncps, *cps])
 
         # -- Protocol 4: secure loss ----------------------------------------
-        l0, l1 = self.model.loss_shares(ctx)
-        cp0.cp.l_self = l0
-        tp.post(msg.LossShare(cps[1], cps[0], l1))
-        tp.pump(order=list(cps))
+        with obs.span("vfl.conductor.p4"):
+            l0, l1 = self.model.loss_shares(ctx)
+            cp0.cp.l_self = l0
+            tp.post(msg.LossShare(cps[1], cps[0], l1))
+            tp.pump(order=list(cps))
 
         # -- stop flag ------------------------------------------------------
-        tp.post_all(self.label_party.emit_flags(self.names[1:]))
-        tp.pump()
-        if hasattr(self.backend, "discard_pooled_noise"):
-            self.backend.discard_pooled_noise()   # bound pool to one iter
+        with obs.span("vfl.conductor.flags"):
+            tp.post_all(self.label_party.emit_flags(self.names[1:]))
+            tp.pump()
+            if hasattr(self.backend, "discard_pooled_noise"):
+                self.backend.discard_pooled_noise()   # bound pool to one iter
 
     # -- step-state machine -------------------------------------------------
     # `run()` is a thin fold over `step()`: every iteration consumes and
@@ -327,31 +338,33 @@ class VFLScheduler:
     def _capture(self, it: int, order, cursor: int,
                  runtime_s: float) -> TrainState:
         be = self.backend
-        pool = 0
-        if hasattr(be, "_noise"):
-            pool = sum(len(q) for q in be._noise.values())
-        shared_select = self.select_rng is self.rng
-        state = TrainState(
-            it=int(it),
-            weights={p.name: np.array(p.W, np.float64)
-                     for p in self.parties},
-            losses=list(self.label_party.losses),
-            stop=bool(self.label_party.stop),
-            order=np.asarray(order, np.int64),
-            cursor=int(cursor),
-            batch_rng=seeds.generator_state(self.batch_rng),
-            jkey=np.asarray(jax.random.key_data(self.jkey)),
-            protocol_rng=self.rng.state(),
-            select_rng=None if shared_select else self.select_rng.state(),
-            dealer=self.dealer.state(),
-            noise_pool_fill=pool,
-            # O(1) prefix view of the append-only ledger — rows are
-            # materialized only at serialization time (session.send_rows)
-            meter_sends=session_lib.LedgerView(self.transport.meter.sends),
-            rounds=int(self.transport.rounds),
-            runtime_s=float(runtime_s))
-        self._live_state = state
-        return state
+        with obs.span("vfl.conductor.capture", it=int(it)):
+            pool = 0
+            if hasattr(be, "_noise"):
+                pool = sum(len(q) for q in be._noise.values())
+            shared_select = self.select_rng is self.rng
+            state = TrainState(
+                it=int(it),
+                weights={p.name: np.array(p.W, np.float64)
+                         for p in self.parties},
+                losses=list(self.label_party.losses),
+                stop=bool(self.label_party.stop),
+                order=np.asarray(order, np.int64),
+                cursor=int(cursor),
+                batch_rng=seeds.generator_state(self.batch_rng),
+                jkey=obs.to_host(jax.random.key_data(self.jkey), "jkey",
+                                 "conductor"),
+                protocol_rng=self.rng.state(),
+                select_rng=None if shared_select else self.select_rng.state(),
+                dealer=self.dealer.state(),
+                noise_pool_fill=pool,
+                # O(1) prefix view of the append-only ledger — rows are
+                # materialized only at serialization time (session.send_rows)
+                meter_sends=session_lib.LedgerView(self.transport.meter.sends),
+                rounds=int(self.transport.rounds),
+                runtime_s=float(runtime_s))
+            self._live_state = state
+            return state
 
     def restore(self, state: TrainState) -> None:
         """Load a TrainState into the live objects.  Idempotent — `step`
@@ -359,25 +372,26 @@ class VFLScheduler:
         the fold's own successor states take the identical path.  All
         stream restores are in-place, so aliases (the HE backend's rng
         handle, a LockedRNG wrapper) see the restored position too."""
-        for p in self.parties:
-            p.W = np.array(state.weights[p.name], np.float64)
-            p.stop = bool(state.stop)
-        self.label_party.losses = list(state.losses)
-        seeds.restore_generator(self.batch_rng, state.batch_rng)
-        self.jkey = jax.random.wrap_key_data(
-            jnp.asarray(np.asarray(state.jkey, np.uint32)))
-        self.rng.set_state(state.protocol_rng)
-        if state.select_rng is not None and self.select_rng is not self.rng:
-            self.select_rng.set_state(state.select_rng)
-        self.dealer.set_state(state.dealer)
-        if hasattr(self.backend, "discard_pooled_noise"):
-            # the pool is data-independent scratch; a resumed iteration
-            # re-prefetches its own batches (state.noise_pool_fill is 0
-            # at every boundary capture)
-            self.backend.discard_pooled_noise()
-        self.transport.meter = rebuild_meter(state.meter_sends)
-        self.transport.rounds = int(state.rounds)
-        self._live_state = state
+        with obs.span("vfl.conductor.capture", it=int(state.it)):
+            for p in self.parties:
+                p.W = np.array(state.weights[p.name], np.float64)
+                p.stop = bool(state.stop)
+            self.label_party.losses = list(state.losses)
+            seeds.restore_generator(self.batch_rng, state.batch_rng)
+            self.jkey = jax.random.wrap_key_data(
+                jnp.asarray(np.asarray(state.jkey, np.uint32)))
+            self.rng.set_state(state.protocol_rng)
+            if state.select_rng is not None and self.select_rng is not self.rng:
+                self.select_rng.set_state(state.select_rng)
+            self.dealer.set_state(state.dealer)
+            if hasattr(self.backend, "discard_pooled_noise"):
+                # the pool is data-independent scratch; a resumed iteration
+                # re-prefetches its own batches (state.noise_pool_fill is 0
+                # at every boundary capture)
+                self.backend.discard_pooled_noise()
+            self.transport.meter = rebuild_meter(state.meter_sends)
+            self.transport.rounds = int(state.rounds)
+            self._live_state = state
 
     def step(self, state: TrainState) -> TrainState:
         """One Algorithm-1 iteration as a state transition.  When
@@ -386,19 +400,20 @@ class VFLScheduler:
         restore is skipped — a deserialized or older state gets the
         full in-place restore."""
         cfg = self.cfg
-        if state is not self._live_state:
-            self.restore(state)
-        t0 = time.perf_counter()
-        order, cursor = state.order, int(state.cursor)
-        if cursor + cfg.batch_size > self.n_total:
-            order = self.batch_rng.permutation(self.n_total)
-            cursor = 0
-        idx = order[cursor:cursor + cfg.batch_size]
-        cursor += cfg.batch_size
-        self._iteration(idx)
-        return self._capture(
-            it=state.it + 1, order=order, cursor=cursor,
-            runtime_s=state.runtime_s + (time.perf_counter() - t0))
+        with obs.span("vfl.conductor.step", it=int(state.it)):
+            if state is not self._live_state:
+                self.restore(state)
+            t0 = time.perf_counter()
+            order, cursor = state.order, int(state.cursor)
+            if cursor + cfg.batch_size > self.n_total:
+                order = self.batch_rng.permutation(self.n_total)
+                cursor = 0
+            idx = order[cursor:cursor + cfg.batch_size]
+            cursor += cfg.batch_size
+            self._iteration(idx)
+            return self._capture(
+                it=state.it + 1, order=order, cursor=cursor,
+                runtime_s=state.runtime_s + (time.perf_counter() - t0))
 
     # -- training loop ------------------------------------------------------
     def run(self, state: TrainState | None = None):

@@ -47,6 +47,7 @@ from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 
 import numpy as np
 
+from repro import obs
 from repro.core.comm import CommMeter
 from repro.runtime.codec import frame_overhead_bytes
 from repro.runtime.messages import Message
@@ -152,11 +153,12 @@ class Transport:
         present at sweep start; handler outputs join the next sweep."""
         priority = list(order or [])
         priority += [n for n in self._parties if n not in priority]
-        while any(self._inbox[n] for n in self._parties):
-            self.rounds += 1
-            snapshot = [(n, len(self._inbox[n])) for n in priority
-                        if self._inbox[n]]
-            self._sweep(snapshot)
+        with obs.span("vfl.transport.pump"):
+            while any(self._inbox[n] for n in self._parties):
+                self.rounds += 1
+                snapshot = [(n, len(self._inbox[n])) for n in priority
+                            if self._inbox[n]]
+                self._sweep(snapshot)
 
     def _deliver_one(self, name: str, count: int) -> list[Message]:
         party = self._parties[name]
@@ -248,25 +250,28 @@ class PipelinedTransport(Transport):
         by design, so callers must only drain order-insensitive phases
         (Protocol 3's ring-share accumulations commute exactly).
         """
-        seed: list[Message] = []
-        names = list(order or [])
-        names += [n for n in self._parties if n not in names]
-        for n in names:
-            q = self._inbox[n]
-            while q:
-                seed.append(q.popleft())
-        futs = {self._pool.submit(self._handle_locked, m): 1 for m in seed}
-        max_gen = 1 if futs else 0
-        while futs:
-            done, _ = wait(set(futs), return_when=FIRST_COMPLETED)
-            for f in done:
-                gen = futs.pop(f)
-                for m in f.result():
-                    if m.src != m.dst:
-                        self.account(m)
-                    futs[self._pool.submit(self._handle_locked, m)] = gen + 1
-                    max_gen = max(max_gen, gen + 1)
-        self.rounds += max_gen
+        with obs.span("vfl.transport.pump"):
+            seed: list[Message] = []
+            names = list(order or [])
+            names += [n for n in self._parties if n not in names]
+            for n in names:
+                q = self._inbox[n]
+                while q:
+                    seed.append(q.popleft())
+            futs = {self._pool.submit(self._handle_locked, m): 1
+                    for m in seed}
+            max_gen = 1 if futs else 0
+            while futs:
+                done, _ = wait(set(futs), return_when=FIRST_COMPLETED)
+                for f in done:
+                    gen = futs.pop(f)
+                    for m in f.result():
+                        if m.src != m.dst:
+                            self.account(m)
+                        fut = self._pool.submit(self._handle_locked, m)
+                        futs[fut] = gen + 1
+                        max_gen = max(max_gen, gen + 1)
+            self.rounds += max_gen
 
 
 # ---------------------------------------------------------------------------
