@@ -20,14 +20,20 @@ Counters are plain numbers in one module-level table; `snapshot()`
 returns a copy and a reader takes the difference of two snapshots:
 
 * ``engine_calls[op]``  calls of a `CryptoEngine` hot-path entry (one
-  kernel launch and its glue on the chip route);
+  kernel launch and its glue on the chip route), and of
+  `paillier.decrypt_crt` (one compiled program);
 * ``h2d_bytes``         bytes of host arrays the hot path turned into
   device arrays (`to_device`);
 * ``gc_pause_s[gen]``, ``gc_collections[gen]``  time in and number of
   the interpreter's garbage collections, per generation.
+
+Inside `uncounted()`, which wraps the body of a compiled program while
+it is traced, the first two count nothing: that body runs once per
+trace, not per launch, and the launch is counted by its caller.
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import threading
 import time
@@ -42,6 +48,7 @@ SPAN_ARGS = frozenset({"it", "party", "key_owner", "rows", "cols", "tag",
 _lock = threading.Lock()
 _counters: dict = {"engine_calls": {}, "h2d_bytes": 0,
                    "gc_pause_s": {}, "gc_collections": {}}
+_uncounted = threading.local()
 
 
 def span(name: str, **args):
@@ -60,7 +67,24 @@ def snapshot() -> dict:
                 for k, v in _counters.items()}
 
 
+@contextlib.contextmanager
+def uncounted():
+    """Count no engine call and no transfer on this thread inside."""
+    prev = _counting()
+    _uncounted.on = True
+    try:
+        yield
+    finally:
+        _uncounted.on = not prev
+
+
+def _counting() -> bool:
+    return not getattr(_uncounted, "on", False)
+
+
 def count_engine_call(op: str) -> None:
+    if not _counting():
+        return
     with _lock:
         calls = _counters["engine_calls"]
         calls[op] = calls.get(op, 0) + 1
@@ -73,8 +97,9 @@ def to_device(x, dtype=None) -> jax.Array:
     if isinstance(x, jax.Array):
         return x if dtype is None or x.dtype == dtype else x.astype(dtype)
     out = jax.device_put(np.asarray(x, dtype))
-    with _lock:
-        _counters["h2d_bytes"] += out.nbytes
+    if _counting():
+        with _lock:
+            _counters["h2d_bytes"] += out.nbytes
     return out
 
 

@@ -5,6 +5,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.crypto import bigint, fixed_point, paillier, ring
+from repro.crypto import engine as engine_mod
 
 KEY = paillier.keygen(128, seed=7)        # small key: fast CPU tests
 PUB = KEY.pub
@@ -111,15 +112,48 @@ def test_u64_bits_msb():
         assert (bits[i] == want).all()
 
 
-@pytest.mark.slow
-def test_crt_decrypt_equals_plain():
-    """CRT decryption (≈4× cheaper) is bit-identical to plain decryption."""
-    key = paillier.keygen(192, seed=13)
+CRT_KEY = paillier.keygen(128, seed=13)
+CRT_ENGINES = {
+    "jnp": engine_mod.CryptoEngine(backend="jnp"),
+    # RNS pinned: at this key size `auto` would route to the library
+    "pallas-interpret": engine_mod.CryptoEngine(backend="pallas-interpret",
+                                                pipeline="rns"),
+}
+
+
+def _pow_decrypt(key, c_mont) -> int:
+    """Textbook decryption with Python ints: L(c^λ mod n²) · μ mod n."""
+    n, n2 = key.pub.n, key.pub.n * key.pub.n
+    r_inv = pow(1 << (bigint.LIMB_BITS * key.pub.Ln2), -1, n2)
+    c = bigint.limbs_to_int(np.asarray(c_mont)) * r_inv % n2
+    return (pow(c, key.lam, n2) - 1) // n * pow(key.lam, -1, n) % n
+
+
+@pytest.mark.parametrize("rows", [None, 9, 10, 12])
+@pytest.mark.parametrize("engine", sorted(CRT_ENGINES))
+def test_crt_decrypt_equals_plain(engine, rows):
+    """CRT decryption (≈4× cheaper) is bit-identical to plain decryption
+    and to Python-int decryption, as one compiled program per key,
+    engine and shape: the first call of a shape traces it once, the
+    next call of that shape does not trace again."""
+    key, eng = CRT_KEY, CRT_ENGINES[engine]
     pub = key.pub
-    rng = np.random.default_rng(5)
-    msgs = [int.from_bytes(rng.bytes(20), "little") % pub.n
-            for _ in range(8)] + [0, 1, pub.n - 1]
-    c = paillier.encrypt(pub, paillier.encode_ints(pub, msgs), rng=rng)
-    plain = paillier.decode_ints(np.asarray(paillier.decrypt(key, c)))
-    crt = paillier.decode_ints(np.asarray(paillier.decrypt_crt(key, c)))
-    assert plain == crt == msgs
+    rng = np.random.default_rng(5 + (rows or 1))
+    msgs = [pub.n - 1]
+    if rows:
+        msgs = [0, 1] + msgs + [int.from_bytes(rng.bytes(16), "little")
+                                % pub.n for _ in range(rows - 3)]
+    m = paillier.encode_ints(pub, msgs)
+    c = paillier.encrypt(pub, m if rows else m[0], rng=rng)
+    programs = paillier._decrypt_crt_program._cache_size()
+    crt = np.asarray(paillier.decrypt_crt(key, c, engine=eng))
+    assert paillier._decrypt_crt_program._cache_size() == programs + 1
+    again = np.asarray(paillier.decrypt_crt(key, c, engine=eng))
+    assert paillier._decrypt_crt_program._cache_size() == programs + 1
+    np.testing.assert_array_equal(again, crt)
+    plain = np.asarray(paillier.decrypt(key, c))
+    np.testing.assert_array_equal(crt, plain)
+    assert crt.shape == c.shape[:-1] + (pub.Ln,)
+    cts = c.reshape(-1, pub.Ln2)
+    assert paillier.decode_ints(crt.reshape(-1, pub.Ln)) == msgs == [
+        _pow_decrypt(key, ci) for ci in cts]

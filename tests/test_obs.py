@@ -146,7 +146,7 @@ def test_identical_steps_count_the_same(toy_steps):
     assert a == b
     assert a["h2d_bytes"] > 0
     assert {"mont_mul", "mont_exp_bits", "he_matvec_windowed", "to_mont",
-            "from_mont"} <= set(a["engine_calls"])
+            "decrypt_crt"} <= set(a["engine_calls"])
 
 
 def test_gc_collection_is_a_span(tmp_path):
@@ -176,6 +176,24 @@ def test_engine_entries_count_once_each():
     moved = {k: v - before.get(k, 0) for k, v in after.items()
              if v != before.get(k, 0)}
     assert moved == {"to_mont": 1, "from_mont": 1}
+
+
+def test_crt_decryption_counts_one_call_per_launch():
+    """A CRT decryption is one compiled program: each call, the first
+    (which traces it) included, counts one `decrypt_crt` and nothing
+    else, neither the engine entries nor the key constants inside."""
+    eng = engine_mod.CryptoEngine(backend="jnp", pipeline="cios")
+    key = paillier.keygen(128, seed=21)
+    c = paillier.encrypt(key.pub, paillier.encode_ints(key.pub, [7, 8]),
+                         rng=np.random.default_rng(0))
+    for _ in range(3):
+        before = obs.snapshot()
+        paillier.decrypt_crt(key, c, engine=eng)
+        after = obs.snapshot()
+        assert _delta(before, after) == {
+            "engine_calls": {op: int(op == "decrypt_crt")
+                             for op in after["engine_calls"]},
+            "h2d_bytes": 0}
 
 
 def test_transfers_count_host_bytes_only():
