@@ -310,7 +310,8 @@ class CryptoEngine:
 
         Args:
           table: a `crypto.fixed_base.FixedBaseTable` for base h mod N
-            (its ``table_rns`` holds ·B-domain channel states).
+            (its ``table_rns`` holds ·B-domain channel states; read from
+            the device copy it keeps, ``device_rns``).
           digits: (..., levels) LSB-first base-2^window exponent digits
             (`fixed_base.exp_digits`).
           mod: the table's modulus (n² for noise tables).
@@ -327,7 +328,7 @@ class CryptoEngine:
         """
         obs.count_engine_call("fixed_base_exp")
         digits = obs.to_device(digits, _U32)
-        table_rns = obs.to_device(table.table_rns, _U32)
+        table_rns = table.device_rns
         if self.uses_kernels:
             from repro.kernels import ops
             return ops.rns_fixed_base_fused(table_rns, digits, mod,

@@ -31,6 +31,7 @@ leave a loadable-but-torn table.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import io
 import json
@@ -41,6 +42,7 @@ import zipfile
 
 import numpy as np
 
+from repro import obs
 from repro.crypto import rns
 from repro.crypto.bigint import Modulus
 
@@ -113,6 +115,15 @@ class FixedBaseTable:
     def nbytes(self) -> int:
         return int(self.table_rns.nbytes)
 
+    @functools.cached_property
+    def device_rns(self):
+        """`table_rns` on the device, sent there once: every encryption
+        under this key walks the same table.  A one-time send of key
+        material, like the key itself, so it is no hot-path transfer
+        (`obs.to_device`) and the counters leave it out."""
+        import jax
+        return jax.device_put(np.asarray(self.table_rns, np.uint32))
+
 
 def exp_digits(exps, levels: int, window: int) -> np.ndarray:
     """LSB-first base-2^window digits: (batch,) ints → (batch, levels)
@@ -155,6 +166,7 @@ def _build_table(purpose: str, n: int, base: int, mod: Modulus, *,
                           table_rns=rows)
 
 
+@obs.key_setup("table_build")
 def build_noise_table(n: int, mod: Modulus, *, window: int = DEFAULT_WINDOW,
                       rho_bits: int = DEFAULT_RHO_BITS,
                       rng: np.random.Generator | None = None,

@@ -176,6 +176,7 @@ def _crt_component(prime: int, n: int) -> CRTComponent:
         mod_p=mod_p)
 
 
+@obs.key_setup("keygen")
 def keygen(key_bits: int, seed: int | None = None, *,
            table_path: str | None = None,
            table_window: int | None = None) -> PrivateKey:

@@ -25,7 +25,17 @@ returns a copy and a reader takes the difference of two snapshots:
 * ``h2d_bytes``         bytes of host arrays the hot path turned into
   device arrays (`to_device`);
 * ``gc_pause_s[gen]``, ``gc_collections[gen]``  time in and number of
-  the interpreter's garbage collections, per generation.
+  the interpreter's garbage collections, per generation;
+* ``jit_s[stage]``      seconds JAX spent making compiled programs, by
+  stage (``trace``, ``lower``, ``compile``: `JIT_STAGES`; and
+  ``cache_load``), from its own duration events.  Stages nest (an eager op compiled
+  while a function traces, a cache read inside a backend compile), so a
+  second is booked once, to the outermost stage open on its thread,
+  except a persistent-cache read, which is booked to ``cache_load`` and
+  taken out of the stage around it;
+* ``keys_s``            seconds in `paillier.keygen` and
+  `fixed_base.build_noise_table` (spans ``vfl.crypto.keygen`` and
+  ``vfl.crypto.table_build``), once where one runs inside the other.
 
 Inside `uncounted()`, which wraps the body of a compiled program while
 it is traced, the first two count nothing: that body runs once per
@@ -47,7 +57,8 @@ SPAN_ARGS = frozenset({"it", "party", "key_owner", "rows", "cols", "tag",
 
 _lock = threading.Lock()
 _counters: dict = {"engine_calls": {}, "h2d_bytes": 0,
-                   "gc_pause_s": {}, "gc_collections": {}}
+                   "gc_pause_s": {}, "gc_collections": {}, "jit_s": {},
+                   "keys_s": 0.0}
 _uncounted = threading.local()
 
 
@@ -137,3 +148,76 @@ def _on_gc(phase: str, info: dict) -> None:
 
 
 gc.callbacks.append(_on_gc)
+
+
+# -- compilation -----------------------------------------------------------
+# JAX opens each stage with a scalar event (its start time) and closes it
+# with a duration event, on the thread that compiles; a persistent-cache
+# read reports only its duration, from inside a backend compile.
+
+JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_jit = threading.local()
+
+
+def _jit_state():
+    if not hasattr(_jit, "open"):
+        _jit.open, _jit.held = [], 0.0
+    return _jit
+
+
+def _on_jit_start(event: str, _value, **_) -> None:
+    if event in JIT_STAGES:
+        _jit_state().open.append(event)
+
+
+def _on_jit_duration(event: str, duration: float, **_) -> None:
+    if event == _CACHE_LOAD:
+        _book_jit("cache_load", duration)
+        _jit_state().held += duration
+        return
+    if event not in JIT_STAGES:
+        return
+    st = _jit_state()
+    if st.open:
+        st.open.pop()
+    if not st.open:
+        _book_jit(JIT_STAGES[event], duration - st.held)
+        st.held = 0.0
+
+
+def _book_jit(stage: str, seconds: float) -> None:
+    with _lock:
+        jit_s = _counters["jit_s"]
+        jit_s[stage] = jit_s.get(stage, 0.0) + seconds
+
+
+jax.monitoring.register_scalar_listener(_on_jit_start)
+jax.monitoring.register_event_duration_secs_listener(_on_jit_duration)
+
+
+# -- keys and tables -------------------------------------------------------
+
+_keys = threading.local()
+
+
+@contextlib.contextmanager
+def key_setup(what: str):
+    """The span ``vfl.crypto.<what>`` around building a key or a table,
+    its seconds added to ``keys_s`` unless it runs inside another
+    (`paillier.keygen` may build the key's noise table)."""
+    depth = getattr(_keys, "depth", 0)
+    _keys.depth = depth + 1
+    t0 = time.perf_counter()
+    try:
+        with span(f"vfl.crypto.{what}"):
+            yield
+    finally:
+        _keys.depth = depth
+        if depth == 0:
+            with _lock:
+                _counters["keys_s"] += time.perf_counter() - t0

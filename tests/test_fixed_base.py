@@ -52,6 +52,26 @@ def test_eval_matches_pow_oracle(keypair, table):
         assert paillier.decode_ints(row)[0] == want
 
 
+def test_table_goes_to_the_device_once(keypair):
+    """Every evaluation reads the one device copy of the table; after it
+    is made, an evaluation sends only its digits."""
+    from repro import obs
+    pub = keypair.pub
+    fresh = fixed_base.build_noise_table(
+        pub.n, pub.mod_n2, window=4, rho_bits=64,
+        rng=np.random.default_rng(5))
+    digits = fixed_base.draw_exponent_digits(fresh, 3,
+                                             np.random.default_rng(2))
+    first = np.asarray(ENG.fixed_base_exp(fresh, digits, pub.mod_n2))
+    resident = fresh.device_rns
+    before = obs.snapshot()["h2d_bytes"]
+    again = np.asarray(ENG.fixed_base_exp(fresh, digits, pub.mod_n2))
+    assert obs.snapshot()["h2d_bytes"] - before == digits.nbytes
+    assert fresh.device_rns is resident
+    np.testing.assert_array_equal(np.asarray(resident), fresh.table_rns)
+    np.testing.assert_array_equal(again, first)
+
+
 def test_draw_digits_uniform_shape(table):
     d = fixed_base.draw_exponent_digits(table, 7, np.random.default_rng(1))
     assert d.shape == (7, table.levels) and d.dtype == np.uint32

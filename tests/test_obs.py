@@ -206,3 +206,64 @@ def test_transfers_count_host_bytes_only():
     host = obs.to_host(R64(dev, dev + 1), "test", "protocol")
     assert isinstance(host, R64) and isinstance(host.lo, np.ndarray)
     np.testing.assert_array_equal(host.lo, np.arange(1, 7))
+
+
+def _jit_events(events):
+    """Feed JAX's stage events to the listeners: ("start", stage) opens
+    one, (stage, seconds) closes it, ("cache", seconds) is a cache read."""
+    names = {v: k for k, v in obs.JIT_STAGES.items()}
+    for kind, arg in events:
+        if kind == "start":
+            obs._on_jit_start(names[arg], 0.0, fun_name="f")
+        elif kind == "cache":
+            obs._on_jit_duration(obs._CACHE_LOAD, arg)
+        else:
+            obs._on_jit_duration(names[kind], arg, fun_name="f")
+
+
+def _jit_delta(before, after):
+    return {k: round(v - before.get(k, 0.0), 9)
+            for k, v in after.items() if v != before.get(k, 0.0)}
+
+
+@pytest.mark.parametrize("events, booked", [
+    # an eager op compiled while a function traces: all of it is tracing
+    ([("start", "trace"), ("start", "compile"), ("compile", 0.5),
+      ("trace", 2.0)], {"trace": 2.0}),
+    # a persistent-cache hit inside a backend compile
+    ([("start", "compile"), ("cache", 0.25), ("compile", 0.75)],
+     {"compile": 0.5, "cache_load": 0.25}),
+    ([("start", "trace"), ("trace", 1.0), ("start", "lower"),
+      ("lower", 0.5), ("start", "compile"), ("compile", 3.0)],
+     {"trace": 1.0, "lower": 0.5, "compile": 3.0}),
+])
+def test_jit_seconds_count_each_second_once(events, booked):
+    before = obs.snapshot()["jit_s"]
+    _jit_events(events)
+    assert _jit_delta(before, obs.snapshot()["jit_s"]) == booked
+
+
+def test_a_jit_counts_its_stages():
+    before = obs.snapshot()["jit_s"]
+    jax.jit(lambda x: jnp.cos(x) * 3)(jnp.arange(5.0)).block_until_ready()
+    moved = _jit_delta(before, obs.snapshot()["jit_s"])
+    assert {"trace", "lower", "compile"} <= set(moved)
+    assert all(v > 0 for v in moved.values())
+
+
+def test_key_and_table_builds_are_counted_once(tmp_path):
+    before = obs.snapshot()["keys_s"]
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        paillier.keygen(256, seed=9, table_path=str(tmp_path / "t.npz"))
+    finally:
+        jax.profiler.stop_trace()
+    spent = obs.snapshot()["keys_s"] - before
+    spans = [s for s in _host_spans(str(tmp_path))
+             if s[0] in ("vfl.crypto.keygen", "vfl.crypto.table_build")]
+    (keygen,), (table,) = ([s for s in spans if s[0] == n] for n in
+                           ("vfl.crypto.keygen", "vfl.crypto.table_build"))
+    assert keygen[1] <= table[1] and table[2] <= keygen[2]
+    # the table is built inside keygen and counted once, with it
+    assert spent <= (keygen[2] - keygen[1]) / 1e9 + 0.05
+    assert spent >= (table[2] - table[1]) / 1e9
