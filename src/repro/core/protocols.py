@@ -434,8 +434,13 @@ def mask_to_r64(R: list[int]) -> R64:
 
 def local_grad_share(feats: EncodedFeatures, d_self: R64) -> R64:
     """Protocol 3 line 2 — a CP's local term X_p^T ⟨d⟩_p."""
-    return _from_col(ring.matmul(obs.to_device(feats.x_int.T),
-                                 _as_col(d_self)))
+    return _local_grad(obs.to_device(feats.x_int.T), d_self)
+
+
+@ring.share_program("local_grad_share")
+def _local_grad(x_t: jnp.ndarray, d: R64) -> R64:
+    g = ring.matmul(x_t, R64(d.hi[:, None], d.lo[:, None]))
+    return R64(g.hi[:, 0], g.lo[:, 0])
 
 
 def masked_matvec(backend, key_owner: str, d_ct, feats: EncodedFeatures,
@@ -505,9 +510,3 @@ def secure_gradient_noncp(
     return total
 
 
-def _as_col(d: R64) -> R64:
-    return R64(d.hi[:, None], d.lo[:, None])
-
-
-def _from_col(g: R64) -> R64:
-    return R64(g.hi[:, 0], g.lo[:, 0])

@@ -6,11 +6,19 @@ ring element is a pair of uint32 lanes and every op is built from 32-bit
 wrap modulo 2^32, which is exactly the semantics we need.
 
 A `R64` is a NamedTuple pytree of two equal-shape uint32 arrays.
+
+Each public op is one compiled program per shape (and static argument:
+shift amount, axis, public integer), so a call from the host is one
+launch, not one per uint32 primitive, and its constants are part of the
+program rather than host scalars sent with each primitive.  Called while
+another program traces, an op inlines into it.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -48,18 +56,38 @@ def to_numpy_u64(x: R64) -> np.ndarray:
     return (hi << np.uint64(32)) | lo
 
 
+def share_program(op: str, static_argnums: tuple[int, ...] = ()):
+    """Compile `body` as one program per shape and static argument; each
+    call from the host counts one ``share_calls[op]`` (`repro.obs`), and
+    a call traced into another program counts nothing."""
+    def wrap(body):
+        program = jax.jit(obs.uncounted()(body),
+                          static_argnums=static_argnums)
+
+        @functools.wraps(body)
+        def call(first, *rest, **kw):
+            obs.count_share_call(
+                op, first[0] if isinstance(first, tuple) else first)
+            return program(first, *rest, **kw)
+        return call
+    return wrap
+
+
+@share_program("add")
 def add(a: R64, b: R64) -> R64:
     lo = a.lo + b.lo
     carry = (lo < a.lo).astype(_U32)
     return R64(a.hi + b.hi + carry, lo)
 
 
+@share_program("sub")
 def sub(a: R64, b: R64) -> R64:
     lo = a.lo - b.lo
     borrow = (a.lo < b.lo).astype(_U32)
     return R64(a.hi - b.hi - borrow, lo)
 
 
+@share_program("neg")
 def neg(a: R64) -> R64:
     return sub(zeros(a.lo.shape), a)
 
@@ -80,6 +108,7 @@ def umul32(a: jnp.ndarray, b: jnp.ndarray):
     return hi, lo
 
 
+@share_program("mul")
 def mul(a: R64, b: R64) -> R64:
     """a*b mod 2^64."""
     hi, lo = umul32(a.lo, b.lo)
@@ -89,12 +118,17 @@ def mul(a: R64, b: R64) -> R64:
 
 def mul_pub_int(a: R64, k: int) -> R64:
     """Multiply by a public python integer (reduced mod 2^64)."""
-    k %= 1 << 64
+    return _mul_pub_int(a, int(k) % (1 << 64))
+
+
+@share_program("mul_pub_int", static_argnums=(1,))
+def _mul_pub_int(a: R64, k: int) -> R64:
     kb = R64(jnp.full(a.lo.shape, (k >> 32) & 0xFFFFFFFF, _U32),
              jnp.full(a.lo.shape, k & 0xFFFFFFFF, _U32))
     return mul(a, kb)
 
 
+@share_program("shift_left", static_argnums=(1,))
 def shift_left(a: R64, s: int) -> R64:
     if s == 0:
         return a
@@ -105,6 +139,7 @@ def shift_left(a: R64, s: int) -> R64:
     return R64((a.hi << s) | (a.lo >> (32 - s)), a.lo << s)
 
 
+@share_program("shift_right_logical", static_argnums=(1,))
 def shift_right_logical(a: R64, s: int) -> R64:
     if s == 0:
         return a
@@ -130,10 +165,12 @@ def to_signed_f64(a: R64, f: int) -> np.ndarray:
     return v.astype(np.float64) / float(1 << f)
 
 
+@share_program("eq")
 def eq(a: R64, b: R64) -> jnp.ndarray:
     return (a.hi == b.hi) & (a.lo == b.lo)
 
 
+@share_program("sum_axis", static_argnums=(1,))
 def sum_axis(a: R64, axis: int) -> R64:
     """Sum along an axis mod 2^64: widen lo into (carry-tracked) pieces.
     Implemented as pairwise tree-reduction using `add` semantics."""
@@ -158,6 +195,7 @@ def sum_axis(a: R64, axis: int) -> R64:
     return R64(cur.hi[0], cur.lo[0])
 
 
+@share_program("matmul")
 def matmul(x_pub_int: jnp.ndarray, a: R64) -> R64:
     """Public signed-int32 matrix times ring matrix — used where one
     operand is public (e.g. X^T times a revealed-masked vector).  For
@@ -173,4 +211,4 @@ def matmul(x_pub_int: jnp.ndarray, a: R64) -> R64:
     xa = R64(xhi[..., :, :, None], xlo[..., :, :, None])
     av = R64(a.hi[..., None, :, :], a.lo[..., None, :, :])
     prod = mul(xa, av)
-    return sum_axis(prod, axis=-2)
+    return sum_axis(prod, -2)

@@ -78,14 +78,25 @@ class DealerTripleSource:
             self._next_key()
 
     def elementwise(self, shape) -> tuple[TripleShares, TripleShares]:
-        ka, kb, ks1, ks2, ks3 = jax.random.split(self._next_key(), 5)
-        a = R64(*prng.u32_pair(ka, shape))
-        b = R64(*prng.u32_pair(kb, shape))
-        c = ring.mul(a, b)
-        a0, a1 = sharing.share(a, ks1)
-        b0, b1 = sharing.share(b, ks2)
-        c0, c1 = sharing.share(c, ks3)
-        return TripleShares(a0, b0, c0), TripleShares(a1, b1, c1)
+        """Both halves of the next triple: one compiled program per
+        shape, the stream advanced by one key split as in `skip`."""
+        self.drawn += 1
+        self._key, t0, t1 = _draw_triple(self._key, tuple(shape))
+        return TripleShares(*t0), TripleShares(*t1)
+
+
+@ring.share_program("dealer_triple", static_argnums=(1,))
+def _draw_triple(key: jax.Array, shape: tuple[int, ...]):
+    """(next stream key, (a0, b0, c0), (a1, b1, c1))."""
+    key, sub = jax.random.split(key)
+    ka, kb, ks1, ks2, ks3 = jax.random.split(sub, 5)
+    a = R64(*prng.u32_pair(ka, shape))
+    b = R64(*prng.u32_pair(kb, shape))
+    c = ring.mul(a, b)
+    a0, a1 = sharing.share(a, ks1)
+    b0, b1 = sharing.share(b, ks2)
+    c0, c1 = sharing.share(c, ks3)
+    return key, (a0, b0, c0), (a1, b1, c1)
 
 
 def open_masked(x0: R64, x1: R64) -> R64:

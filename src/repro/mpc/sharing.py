@@ -14,8 +14,10 @@ from repro.crypto import prng, ring
 from repro.crypto.ring import R64
 
 
+@ring.share_program("share")
 def share(x: R64, key: jax.Array) -> tuple[R64, R64]:
-    """x -> (⟨x⟩_0, ⟨x⟩_1), ⟨x⟩_0 uniform (Theorem 2's PRNG assumption)."""
+    """x -> (⟨x⟩_0, ⟨x⟩_1), ⟨x⟩_0 uniform (Theorem 2's PRNG assumption).
+    One compiled program per shape."""
     hi, lo = prng.u32_pair(key, x.lo.shape)
     s0 = R64(hi, lo)
     s1 = ring.sub(x, s0)

@@ -22,6 +22,10 @@ returns a copy and a reader takes the difference of two snapshots:
 * ``engine_calls[op]``  calls of a `CryptoEngine` hot-path entry (one
   kernel launch and its glue on the chip route), and of
   `paillier.decrypt_crt` (one compiled program);
+* ``share_calls[op]``   calls of a compiled share-ring program
+  (`crypto.ring.share_program`: a Z_2^64 op, the dealer's triple draw,
+  a share split) made from the host; one traced into another program
+  counts nothing;
 * ``h2d_bytes``         bytes of host arrays the hot path turned into
   device arrays (`to_device`);
 * ``gc_pause_s[gen]``, ``gc_collections[gen]``  time in and number of
@@ -38,7 +42,7 @@ returns a copy and a reader takes the difference of two snapshots:
   ``vfl.crypto.table_build``), once where one runs inside the other.
 
 Inside `uncounted()`, which wraps the body of a compiled program while
-it is traced, the first two count nothing: that body runs once per
+it is traced, the first three count nothing: that body runs once per
 trace, not per launch, and the launch is counted by its caller.
 """
 from __future__ import annotations
@@ -56,7 +60,7 @@ SPAN_ARGS = frozenset({"it", "party", "key_owner", "rows", "cols", "tag",
                        "gen"})
 
 _lock = threading.Lock()
-_counters: dict = {"engine_calls": {}, "h2d_bytes": 0,
+_counters: dict = {"engine_calls": {}, "share_calls": {}, "h2d_bytes": 0,
                    "gc_pause_s": {}, "gc_collections": {}, "jit_s": {},
                    "keys_s": 0.0}
 _uncounted = threading.local()
@@ -80,7 +84,8 @@ def snapshot() -> dict:
 
 @contextlib.contextmanager
 def uncounted():
-    """Count no engine call and no transfer on this thread inside."""
+    """Count no engine call, share call or transfer on this thread
+    inside."""
     prev = _counting()
     _uncounted.on = True
     try:
@@ -98,6 +103,17 @@ def count_engine_call(op: str) -> None:
         return
     with _lock:
         calls = _counters["engine_calls"]
+        calls[op] = calls.get(op, 0) + 1
+
+
+def count_share_call(op: str, probe) -> None:
+    """Count a share-ring program call; `probe`, one array argument of
+    the call, is a tracer when the call is traced into another program,
+    which launches it, and then nothing is counted."""
+    if not _counting() or isinstance(probe, jax.core.Tracer):
+        return
+    with _lock:
+        calls = _counters["share_calls"]
         calls[op] = calls.get(op, 0) + 1
 
 

@@ -17,7 +17,10 @@ from repro.core import protocols
 from repro.core.trainer import PartyData, VFLConfig
 from repro.crypto import engine as engine_mod
 from repro.crypto import paillier
+from repro.crypto import ring
 from repro.crypto.ring import R64
+from repro.mpc import beaver, sharing
+from repro.mpc.truncation import trunc_share
 from repro.runtime import LocalTransport, VFLScheduler
 
 PARTIES = ("C", "B1", "B2")
@@ -194,6 +197,27 @@ def test_crt_decryption_counts_one_call_per_launch():
             "engine_calls": {op: int(op == "decrypt_crt")
                              for op in after["engine_calls"]},
             "h2d_bytes": 0}
+
+
+def test_share_calls_count_host_calls_only():
+    """A compiled share-ring program counts one call per launch from the
+    host, the first (which traces it) included; the programs it calls
+    inside, and any called while another program traces, count none."""
+    x = ring.from_numpy_u64(np.arange(1, 5, dtype=np.uint64))
+    dealer = beaver.DealerTripleSource(seed=7)
+    traced = jax.jit(lambda a: ring.add(ring.mul(a, a), ring.neg(a)))
+    for _ in range(2):
+        before = obs.snapshot()["share_calls"]
+        ring.mul_pub_int(ring.add(x, x), -3)        # mul inlines
+        traced(x)
+        dealer.elementwise((4,))                    # mul, share inline
+        sharing.share(x, jax.random.key(1))         # sub inlines
+        trunc_share(x, 5, 1)                        # neg, shift, neg
+        after = obs.snapshot()["share_calls"]
+        moved = {k: v - before.get(k, 0) for k, v in after.items()
+                 if v != before.get(k, 0)}
+        assert moved == {"add": 1, "mul_pub_int": 1, "dealer_triple": 1,
+                         "share": 1, "neg": 2, "shift_right_logical": 1}
 
 
 def test_transfers_count_host_bytes_only():
